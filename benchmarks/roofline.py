@@ -5,7 +5,9 @@
     collective_s = collective_bytes_per_device / 50e9 (per-link ICI)
 
 ``cost_analysis()`` semantics (per-device vs global) are *calibrated* in a
-subprocess against a matmul of known FLOPs before being trusted.  The
+CPU-only subprocess (8 virtual devices, ``JAX_PLATFORMS=cpu``, so it never
+contends for an accelerator the parent holds) against a matmul of known
+FLOPs before being trusted.  The
 dominant term, MODEL_FLOPS=6ND (or 6·N_active·D) ratio, and a what-to-fix
 hint are derived per cell; output feeds EXPERIMENTS.md §Roofline directly.
 """
@@ -13,9 +15,11 @@ hint are derived per cell; output feeds EXPERIMENTS.md §Roofline directly.
 from __future__ import annotations
 
 import json
-import numpy as np
+import os
 import subprocess
 import sys
+
+import numpy as np
 from pathlib import Path
 
 from .common import ROOT, emit, save_result
@@ -31,17 +35,14 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("d",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("d",))
 xs = jax.ShapeDtypeStruct((1024, 512), jnp.float32)
 ws = jax.ShapeDtypeStruct((512, 256), jnp.float32)
 f = jax.jit(lambda x, w: x @ w,
             in_shardings=(NamedSharding(mesh, P("d", None)), NamedSharding(mesh, P())))
 c = f.lower(xs, ws).compile()
-ca = c.cost_analysis()
-if isinstance(ca, list):  # old jax returns one dict per computation
-    ca = ca[0]
-flops = ca["flops"]
+flops = c.cost_analysis()["flops"]
 global_flops = 2 * 1024 * 512 * 256
 print(flops / global_flops)
 """
@@ -49,8 +50,10 @@ print(flops / global_flops)
 
 def calibrate() -> float:
     """Returns cost_analysis flops / global flops (≈1/n_dev ⇒ per-device)."""
-    out = subprocess.run([sys.executable, "-c", _CALIB_SRC],
-                         capture_output=True, text=True, timeout=300)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", _CALIB_SRC], env=env,
+                         capture_output=True, text=True, timeout=300,
+                         check=True)
     ratio = float(out.stdout.strip().splitlines()[-1])
     return ratio
 

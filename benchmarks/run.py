@@ -3,11 +3,14 @@
 Emits ``name,us_per_call,derived`` CSV lines (the scaffold contract) and
 writes structured JSON to experiments/results/.  ``--quick`` shrinks data
 sizes for smoke use; default sizes reproduce the paper-comparable numbers.
+A suite that raises is reported and the remaining suites still run, but
+the command then exits non-zero.
 """
 
 import argparse
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -22,6 +25,9 @@ def main() -> None:
                          "not given")
     args = ap.parse_args()
 
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (codec_speed, fig3_code_compression, graph_bench,
                    ingest_bench, moe_routing, roofline, select_bench,
                    shard_bench, spec_bench, table1_bpe, table2_search_time,
@@ -45,15 +51,20 @@ def main() -> None:
     }
     chosen = args.only or (["spec"] if args.spec else
                            [n for n in suites if n != "spec"])
+    failed = []
     for name in chosen:
         t0 = time.time()
         print(f"# --- {name} ---", flush=True)
         try:
             suites[name](quick=args.quick)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - reported, fails the command
+            traceback.print_exc()
             print(f"{name}/FAILED,0.0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
             continue
         print(f"# {name} done in {time.time()-t0:.1f}s", flush=True)
+    if failed:
+        sys.exit(f"failed suites: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

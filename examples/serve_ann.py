@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.api import index_factory
 from repro.data.synthetic import make_dataset
+from repro.jax_cache import enable_compile_cache
 from repro.serve import AnnService, BatchPolicy
 
 
@@ -67,6 +68,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.fault_rate and not args.shards:
         ap.error("--fault-rate needs --shards")
+    enable_compile_cache()
 
     print(f"dataset: {args.n} x 128 (sift-like)")
     base, queries = make_dataset("sift-like", args.n, args.queries, seed=0)

@@ -51,7 +51,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from .scan import _bucket, _jax, _resolve_engine, rescore_eps
+from .scan import _bucket, _jax, _l2_xla, _resolve_engine, rescore_eps
 from .stats import SearchStats
 
 __all__ = ["batched_graph_search"]
@@ -76,7 +76,7 @@ def _graph_scorers():
     jax, jnp = _jax(), _jax().numpy
 
     @functools.partial(jax.jit, static_argnames=("interpret",))
-    def pallas(q, xdev, idx, interpret=True):
+    def pallas(q, xdev, idx, interpret):
         from ..kernels.l2_topk import l2_dist
 
         return l2_dist(q, xdev[idx], block_q=GRAPH_BLOCK_Q,
@@ -84,10 +84,7 @@ def _graph_scorers():
 
     @jax.jit
     def xla(q, xdev, idx):
-        a = xdev[idx]
-        qn = jnp.sum(q * q, axis=1, keepdims=True)
-        an = jnp.sum(a * a, axis=1)
-        return qn - 2.0 * q @ a.T + an[None]
+        return _l2_xla(q, xdev[idx])
 
     # device-select variants (``select="device"``): same scorer expression,
     # but the per-candidate distance vector ``dmat[step_row, arange]`` is
@@ -95,7 +92,7 @@ def _graph_scorers():
     # never the (qb_pad, n_pad) step block.  Same floats as the host
     # gather, so the prune band (and hence the trajectory) is unchanged.
     @functools.partial(jax.jit, static_argnames=("interpret",))
-    def pallas_vec(q, xdev, idx, step_row, interpret=True):
+    def pallas_vec(q, xdev, idx, step_row, interpret):
         from ..kernels.l2_topk import l2_dist
 
         dmat = l2_dist(q, xdev[idx], block_q=GRAPH_BLOCK_Q,
@@ -104,10 +101,7 @@ def _graph_scorers():
 
     @jax.jit
     def xla_vec(q, xdev, idx, step_row):
-        a = xdev[idx]
-        qn = jnp.sum(q * q, axis=1, keepdims=True)
-        an = jnp.sum(a * a, axis=1)
-        dmat = qn - 2.0 * q @ a.T + an[None]
+        dmat = _l2_xla(q, xdev[idx])
         return dmat[step_row, jnp.arange(idx.shape[0])]
 
     return {"pallas": pallas, "xla": xla,

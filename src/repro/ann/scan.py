@@ -312,21 +312,33 @@ def _jax():
     return jax
 
 
+def _l2_xla(q, a):
+    """``(qb, d) x (n, d) -> (qb, n)`` expanded squared L2 in plain XLA.
+
+    The dot runs at ``Precision.HIGHEST``: a TPU's default f32 matmul is
+    one bf16 pass, whose error would exceed the ``rescore_eps`` band the
+    exact re-score relies on (the CPU ignores the flag).
+    """
+    jnp = _jax().numpy
+    qn = jnp.sum(q * q, axis=1, keepdims=True)
+    an = jnp.sum(a * a, axis=1)
+    dots = jnp.matmul(q, a.T, precision=_jax().lax.Precision.HIGHEST)
+    return qn - 2.0 * dots + an[None]
+
+
 @functools.lru_cache(maxsize=None)
 def _flat_scorers():
     jax, jnp = _jax(), _jax().numpy
 
     @functools.partial(jax.jit, static_argnames=("interpret",))
-    def pallas(q, a, interpret=True):
+    def pallas(q, a, interpret):
         from ..kernels.l2_topk import l2_dist
 
         return l2_dist(q, a, interpret=interpret)
 
     @jax.jit
     def xla(q, a):
-        qn = jnp.sum(q * q, axis=1, keepdims=True)
-        an = jnp.sum(a * a, axis=1)
-        return qn - 2.0 * q @ a.T + an[None]
+        return _l2_xla(q, a)
 
     return {"pallas": pallas, "xla": xla}
 
@@ -336,7 +348,7 @@ def _adc_scorers():
     jax, jnp = _jax(), _jax().numpy
 
     @functools.partial(jax.jit, static_argnames=("interpret",))
-    def pallas(luts, codes, interpret=True):
+    def pallas(luts, codes, interpret):
         from ..kernels.pq_adc import pq_adc
 
         # vmap over per-query LUTs; codes (the arena) are shared.
@@ -421,13 +433,9 @@ def _resolve_select(select: str, c_pad: int, select_min: int) -> bool:
 
 def _resolve_engine(engine: str) -> str:
     if engine == "auto":
-        try:
-            backend = _jax().default_backend()
-        except (ImportError, RuntimeError):  # pragma: no cover - no backend
-            backend = "cpu"
         # interpret-mode Pallas is a correctness path, not a fast path:
         # on CPU the plain-XLA scorer is the performant batched fallback.
-        return "pallas" if backend != "cpu" else "xla"
+        return "pallas" if _jax().default_backend() != "cpu" else "xla"
     if engine not in ("pallas", "xla"):
         raise ValueError(f"unknown scan engine {engine!r}")
     return engine
@@ -787,9 +795,7 @@ def _flat_select_runner():
 
             dmat = l2_dist(qblk, base, interpret=interpret)
         else:
-            qn = jnp.sum(qblk * qblk, axis=1, keepdims=True)
-            bn = jnp.sum(base * base, axis=1)
-            dmat = qn - 2.0 * qblk @ base.T + bn[None]
+            dmat = _l2_xla(qblk, base)
         lens = jnp.full(qblk.shape[0], nvalid, jnp.int32)
         if engine == "pallas":
             return seg_topk(dmat, lens, k, interpret=interpret)

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import pvary, shard_map
-
 __all__ = ["pipeline_apply"]
 
 
@@ -43,15 +41,15 @@ def pipeline_apply(stage_fn: Callable, n_stages: int, n_micro: int,
             buf = xs  # (n_micro, mb, ...)
             # carries are device-varying (each stage holds different data):
             # mark them as such for shard_map's vma type system
-            carry = pvary(jnp.zeros_like(xs[0]), (axis,))
-            outs = pvary(jnp.zeros_like(xs), (axis,))
+            carry = jax.lax.pvary(jnp.zeros_like(xs[0]), (axis,))
+            outs = jax.lax.pvary(jnp.zeros_like(xs), (axis,))
 
             def step(t, state):
                 carry, outs = state
                 # stage 0 injects microbatch t; others take the permuted carry
                 inject = jax.lax.dynamic_index_in_dim(
                     buf, jnp.clip(t, 0, n_micro - 1), keepdims=False)
-                inp = jnp.where(stage_id == 0, pvary(inject, (axis,)),
+                inp = jnp.where(stage_id == 0, jax.lax.pvary(inject, (axis,)),
                                 carry)
                 active = (t >= stage_id) & (t - stage_id < n_micro)
                 out = jnp.where(active, stage_fn(params, inp), inp)
@@ -73,7 +71,7 @@ def pipeline_apply(stage_fn: Callable, n_stages: int, n_micro: int,
                 axis)
             return outs
 
-        return shard_map(
+        return jax.shard_map(
             per_stage, mesh=mesh,
             in_specs=(P(axis), P()),
             out_specs=P(),

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import pvary, shard_map
-
 __all__ = ["sp_decode_attention"]
 
 
@@ -58,7 +56,7 @@ def sp_decode_attention(q, k_shard, v_shard, valid_mask, axis: str = "model"):
 def make_sp_decode(mesh, axis: str = "model"):
     """shard_map wrapper: full-shape (B,1,H,D) q + seq-sharded (B,T,KV,D)."""
     def fn(q, k, v, valid):
-        return shard_map(
+        return jax.shard_map(
             lambda q_, k_, v_, m_: sp_decode_attention(q_, k_, v_, m_, axis),
             mesh=mesh,
             in_specs=(P(), P(None, axis, None, None),

@@ -1,9 +1,13 @@
 """repro.kernels — Pallas TPU kernels for the paper's compute hot-spots.
 
 Each kernel package pairs a Pallas implementation (``kernel.py``,
-targeted at TPU v5e BlockSpec tiling, validated in interpret mode on
-CPU) with a jitted wrapper (``ops.py``) and a pure-jnp oracle
-(``ref.py``):
+written to TPU v5e tiling rules: 2-D, lane-dense blocks) with a jitted
+wrapper (``ops.py``) and a pure-jnp oracle (``ref.py``).  The wrappers
+take ``interpret=None`` by default, which compiles the kernel with Mosaic
+on a TPU and runs the Pallas interpreter on the CPU backend; the
+serving-path kernels (``l2_topk``, ``pq_adc``, ``seg_topk``) are also
+AOT-compiled for v5e in ``tests/test_tpu_compile.py`` and run compiled
+by ``chip_smoke.py``:
 
 * ``l2_topk``   — blocked squared-L2 distance matrix / top-1 scan.
 * ``pq_adc``    — PQ asymmetric-distance (ADC) lookup-table scoring.

@@ -5,15 +5,22 @@ queries, compute squared L2 distances to all K centroids with one MXU
 matmul (||q||^2 - 2 q.c + ||c||^2) and reduce to (argmin, min) without
 writing the (BLOCK_Q, K) distance tile to HBM.
 
-Grid: (ceil(NQ / BLOCK_Q),).  Centroids (and their norms) are VMEM-resident
-across grid steps (constant index_map): K*d*4 bytes — e.g. 2048 x 128 f32
-= 1 MB.  MXU dims: BLOCK_Q x d x K, all multiples of 128 by construction
-(ops.py pads d and K).
+Grid: (ceil(NQ / BLOCK_Q),).  Centroids are VMEM-resident across grid
+steps (constant index_map): K*d*4 bytes — e.g. 2048 x 128 f32 = 1 MB.
+MXU dims: BLOCK_Q x d x K, all multiples of 128 by construction (ops.py
+pads d and K).
+
+TPU layout rules shape both kernels: every block is 2-D (Mosaic and XLA
+tile rank-1 arrays differently), so the candidate norms are computed in
+the kernel from a transposed ``(d, block)`` view — the lane-dense row
+``||c||^2`` broadcasts straight against the ``(rows, block)`` dot tile —
+and per-query outputs are ``(rows, 1)`` columns.  Dots run at
+``Precision.HIGHEST`` (f32 contraction): the scan engines' exact
+re-score band (``repro.ann.scan.rescore_eps``) assumes f32 error, which a
+one-pass bf16 MXU product would exceed.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,24 +32,32 @@ BLOCK_Q = 256
 BLOCK_N = 512
 
 
-def _l2_kernel(q_ref, c_ref, cn_ref, idx_ref, val_ref):
-    q = q_ref[...]                       # (BLOCK_Q, d)
-    c = c_ref[...]                       # (K, d)
-    cn = cn_ref[...]                     # (K,)
-    dots = jnp.dot(q, c.T, preferred_element_type=jnp.float32)
-    qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=1, keepdims=True)
-    dist = qn - 2.0 * dots + cn[None, :]
-    idx_ref[...] = jnp.argmin(dist, axis=1).astype(jnp.int32)
-    val_ref[...] = jnp.min(dist, axis=1)
+def _l2_tile(q, c):
+    """(rows, d) x (block, d) -> (rows, block) squared L2, f32."""
+    ct = c.astype(jnp.float32).T                               # (d, block)
+    dots = jnp.dot(q, ct, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+    qn = jnp.sum(q * q, axis=1, keepdims=True)                 # (rows, 1)
+    cn = jnp.sum(ct * ct, axis=0, keepdims=True)               # (1, block)
+    return qn - 2.0 * dots + cn
+
+
+def _l2_kernel(q_ref, c_ref, idx_ref, val_ref):
+    dist = _l2_tile(q_ref[...], c_ref[...])                    # (BLOCK_Q, K)
+    m = jnp.min(dist, axis=1, keepdims=True)
+    cols = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
+    # first column attaining the minimum (argmin's tie rule)
+    idx_ref[...] = jnp.min(jnp.where(dist == m, cols, dist.shape[1]),
+                           axis=1, keepdims=True)
+    val_ref[...] = m
 
 
 def l2_top1_pallas(queries: jnp.ndarray, centroids: jnp.ndarray,
-                   block_q: int = BLOCK_Q, interpret: bool = True):
-    """queries (NQ, d), centroids (K, d) -> (argmin (NQ,) i32, min (NQ,) f32)."""
+                   block_q: int = BLOCK_Q, *, interpret: bool):
+    """queries (NQ, d), centroids (K, d) -> (argmin (NQ, 1) i32, min (NQ, 1) f32)."""
     nq, d = queries.shape
     k = centroids.shape[0]
     assert nq % block_q == 0
-    cn = jnp.sum(centroids.astype(jnp.float32) ** 2, axis=1)
     grid = (nq // block_q,)
     return pl.pallas_call(
         _l2_kernel,
@@ -50,32 +65,26 @@ def l2_top1_pallas(queries: jnp.ndarray, centroids: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i: (i, 0)),
             pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-            pl.BlockSpec((block_q,), lambda i: (i,)),
+            pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq,), jnp.int32),
-            jax.ShapeDtypeStruct((nq,), jnp.float32),
+            jax.ShapeDtypeStruct((nq, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(queries, centroids, cn)
+    )(queries, centroids)
 
 
-def _l2_dist_kernel(q_ref, c_ref, cn_ref, out_ref):
-    q = q_ref[...]                       # (block_q, d)
-    c = c_ref[...]                       # (block_n, d)
-    cn = cn_ref[...]                     # (block_n,)
-    dots = jnp.dot(q, c.T, preferred_element_type=jnp.float32)
-    qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=1, keepdims=True)
-    out_ref[...] = qn - 2.0 * dots + cn[None, :]
+def _l2_dist_kernel(q_ref, c_ref, out_ref):
+    out_ref[...] = _l2_tile(q_ref[...], c_ref[...])
 
 
 def l2_dist_pallas(queries: jnp.ndarray, cands: jnp.ndarray,
-                   block_q: int = BLOCK_Q, block_n: int = BLOCK_N,
-                   interpret: bool = True):
+                   block_q: int = BLOCK_Q, block_n: int = BLOCK_N, *,
+                   interpret: bool):
     """queries (NQ, d), cands (N, d) -> full (NQ, N) squared-L2 matrix.
 
     The batched-IVF scan shape: one query tile against candidate tiles
@@ -87,7 +96,6 @@ def l2_dist_pallas(queries: jnp.ndarray, cands: jnp.ndarray,
     nq, d = queries.shape
     n = cands.shape[0]
     assert nq % block_q == 0 and n % block_n == 0
-    cn = jnp.sum(cands.astype(jnp.float32) ** 2, axis=1)
     grid = (nq // block_q, n // block_n)
     return pl.pallas_call(
         _l2_dist_kernel,
@@ -95,9 +103,8 @@ def l2_dist_pallas(queries: jnp.ndarray, cands: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
         ],
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nq, n), jnp.float32),
         interpret=interpret,
-    )(queries, cands, cn)
+    )(queries, cands)

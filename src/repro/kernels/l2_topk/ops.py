@@ -5,13 +5,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .._interpret import resolve_interpret
 from .kernel import BLOCK_N, BLOCK_Q, l2_dist_pallas, l2_top1_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_q"))
-def l2_top1(queries, centroids, block_q: int = BLOCK_Q, interpret: bool = True):
+def l2_top1(queries, centroids, block_q: int = BLOCK_Q,
+            interpret: bool | None = None):
     """Nearest centroid per query: ``(argmin (nq,) i32, min_d (nq,) f32)``
-    over squared L2, padded to kernel block shapes."""
+    over squared L2, padded to kernel block shapes.  ``interpret=None``
+    interprets on the CPU backend and compiles everywhere else."""
     nq, d = queries.shape
     k = centroids.shape[0]
     if nq == 0 or k == 0:
@@ -25,20 +28,22 @@ def l2_top1(queries, centroids, block_q: int = BLOCK_Q, interpret: bool = True):
     cp = jnp.pad(centroids.astype(jnp.float32), ((0, pad_k), (0, pad_d)))
     if pad_k:
         cp = cp.at[k:, 0].set(3e18)
-    idx, val = l2_top1_pallas(qp, cp, block_q=block_q, interpret=interpret)
-    return idx[:nq], val[:nq]
+    idx, val = l2_top1_pallas(qp, cp, block_q=block_q,
+                              interpret=resolve_interpret(interpret))
+    return idx[:nq, 0], val[:nq, 0]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "block_q", "block_n"))
 def l2_dist(queries, cands, block_q: int = BLOCK_Q, block_n: int = BLOCK_N,
-            interpret: bool = True):
+            interpret: bool | None = None):
     """queries (NQ, d), cands (N, d) -> (NQ, N) f32 squared L2 distances.
 
     Zero-pads d (distance-preserving) and both row counts to block
     multiples; padded rows/columns are sliced off, so callers never see
     them.  NQ = 0 or N = 0 short-circuits to an empty result (Pallas grids
-    must be non-empty).
+    must be non-empty).  ``interpret=None`` interprets on the CPU backend
+    and compiles everywhere else.
     """
     nq, d = queries.shape
     n = cands.shape[0]
@@ -50,5 +55,5 @@ def l2_dist(queries, cands, block_q: int = BLOCK_Q, block_n: int = BLOCK_N,
     qp = jnp.pad(queries.astype(jnp.float32), ((0, pad_q), (0, pad_d)))
     cp = jnp.pad(cands.astype(jnp.float32), ((0, pad_n), (0, pad_d)))
     out = l2_dist_pallas(qp, cp, block_q=block_q, block_n=block_n,
-                         interpret=interpret)
+                         interpret=resolve_interpret(interpret))
     return out[:nq, :n]

@@ -51,7 +51,7 @@ def _decode_kernel(heads_ref, words_ref, sym_t_ref, freq_t_ref, start_t_ref,
         w = jnp.take(words_ref[...], idx).astype(jnp.uint32)
         heads = jnp.where(need, (heads << jnp.uint32(16)) | w, heads)
         ptr = ptr + jnp.sum(need.astype(jnp.int32))
-        pl.store(out_ref, (pl.dslice(t, 1), slice(None)), sym[None, :])
+        out_ref[pl.ds(t, 1), :] = sym[None, :]
         return heads, ptr
 
     heads0 = heads_ref[...]
@@ -60,7 +60,7 @@ def _decode_kernel(heads_ref, words_ref, sym_t_ref, freq_t_ref, start_t_ref,
 
 
 def rans_decode_pallas(heads, words, sym_t, freq_t, start_t, rows: int,
-                       r: int, interpret: bool = True):
+                       r: int, *, interpret: bool):
     """heads (L,) u32; words (W,) u32 (16-bit values); tables (2^r,) i32.
 
     Returns (rows, L) int32 symbols (row-major decode order).

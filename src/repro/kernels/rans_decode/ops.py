@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .._interpret import resolve_interpret
 from .kernel import LANES, rans_decode_pallas
 
 
@@ -25,11 +26,15 @@ def make_tables(freqs: np.ndarray, r: int):
 
 @functools.partial(jax.jit, static_argnames=("rows", "r", "interpret"))
 def rans_decode(heads, words, sym_t, freq_t, start_t, rows: int, r: int,
-                interpret: bool = True):
-    """Decode rows*L symbols; heads (L,) u32, words (W,) u16/u32."""
+                interpret: bool | None = None):
+    """Decode rows*L symbols; heads (L,) u32, words (W,) u16/u32.
+
+    ``interpret=None`` interprets on the CPU backend and compiles
+    everywhere else."""
     L = heads.shape[0]
     words = jnp.pad(words.astype(jnp.uint32), (0, L))  # slack for masked gathers
     return rans_decode_pallas(
         heads.astype(jnp.uint32), words,
         sym_t.astype(jnp.int32), freq_t.astype(jnp.int32),
-        start_t.astype(jnp.int32), rows, r, interpret=interpret)
+        start_t.astype(jnp.int32), rows, r,
+        interpret=resolve_interpret(interpret))

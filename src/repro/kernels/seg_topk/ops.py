@@ -22,27 +22,34 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import SEG_BLOCK_Q, seg_topk_pallas
+from .._interpret import resolve_interpret
+from .kernel import SEG_BLOCK_Q, SEG_CHUNK, seg_topk_pallas
 
 __all__ = ["seg_topk", "seg_topk_xla"]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_q", "interpret"))
 def seg_topk(dists, lens, k: int, block_q: int = SEG_BLOCK_Q,
-             interpret: bool = True):
-    """Pallas engine: pad rows/columns to kernel shape, select on device."""
+             interpret: bool | None = None):
+    """Pallas engine: pad rows/columns to kernel shape, select on device.
+
+    ``interpret=None`` interprets on the CPU backend and compiles
+    everywhere else."""
     nq, n = dists.shape
     if nq == 0 or k == 0:
         return (jnp.full((nq, k), jnp.inf, jnp.float32),
                 jnp.zeros((nq, k), jnp.int32))
     lens = jnp.minimum(lens.astype(jnp.int32), n)
     n_eff = max(n, k)
+    n_eff += (-n_eff) % 128
+    chunk = min(n_eff, SEG_CHUNK)
     pad_q = (-nq) % block_q
-    pad_n = (-n_eff) % 128 + (n_eff - n)
+    pad_n = n_eff + (-n_eff) % chunk - n
     dp = jnp.pad(dists.astype(jnp.float32), ((0, pad_q), (0, pad_n)))
-    lp = jnp.pad(lens, (0, pad_q))          # padding rows: lens 0, all +inf
-    vals, idx = seg_topk_pallas(dp, lp, k, block_q=block_q,
-                                interpret=interpret)
+    # padding rows: lens 0, all +inf
+    lp = jnp.pad(lens, (0, pad_q))[:, None]
+    vals, idx = seg_topk_pallas(dp, lp, k, block_q=block_q, chunk=chunk,
+                                interpret=resolve_interpret(interpret))
     return vals[:nq], idx[:nq]
 
 

@@ -51,8 +51,8 @@ def _rank_kernel(words_ref, super_ref, q_ref, out_ref):
     out_ref[...] = acc.astype(jnp.int32)
 
 
-def wt_rank_pallas(words, super_cum, queries, block_q: int = BLOCK_Q,
-                   interpret: bool = True):
+def wt_rank_pallas(words, super_cum, queries, block_q: int = BLOCK_Q, *,
+                   interpret: bool):
     nq = queries.shape[0]
     assert nq % block_q == 0
     W = words.shape[0]

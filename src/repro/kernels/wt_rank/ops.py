@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .._interpret import resolve_interpret
 from .kernel import BLOCK_Q, WORDS_PER_SUPER, wt_rank_pallas
 
 
@@ -27,11 +28,14 @@ def pack_bits_u32(bits: np.ndarray):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def wt_rank(words, super_cum, queries, interpret: bool = True):
+def wt_rank(words, super_cum, queries, interpret: bool | None = None):
     """``rank1(i)`` for each query position over the packed bitvector:
-    superblock cumulative popcounts + an in-block popcount on device."""
+    superblock cumulative popcounts + an in-block popcount on device.
+    ``interpret=None`` interprets on the CPU backend and compiles
+    everywhere else."""
     nq = queries.shape[0]
     pad = (-nq) % BLOCK_Q
     q = jnp.pad(queries.astype(jnp.int32), (0, pad))
-    out = wt_rank_pallas(words, super_cum, q, interpret=interpret)
+    out = wt_rank_pallas(words, super_cum, q,
+                         interpret=resolve_interpret(interpret))
     return out[:nq]

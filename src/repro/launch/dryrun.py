@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import SHAPES, get_config, shape_applicable, ARCH_IDS
-from repro.launch.mesh import use_mesh
 from repro.distributed.sharding import (
     batch_shardings,
     cache_shardings,
@@ -135,7 +134,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, step_kind: str | Non
     params_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     p_shard = param_shardings(params_shapes, mesh, cfg.n_experts)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             _, train_step = make_train_step(cfg)
             opt_shapes = jax.eval_shape(init_opt, params_shapes)
@@ -237,7 +236,7 @@ def _lower_probe(cfg, shape, kind, mesh):
     model = build(cfg)
     params_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     p_shard = param_shardings(params_shapes, mesh, cfg.n_experts)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             _, step = make_train_step(cfg, unroll=True)
             opt_shapes = jax.eval_shape(init_opt, params_shapes)

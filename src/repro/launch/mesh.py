@@ -5,40 +5,28 @@ module never touches jax device initialization.  The dry-run launcher sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import; everything else in the repo sees the real (single) device.
 
-``make_mesh_compat`` / ``use_mesh`` paper over the jax API drift around
-meshes: ``axis_types=`` and ``jax.set_mesh`` only exist on newer jax;
-on older versions Auto axes are the default and the ``Mesh`` object itself
-is the context manager.
+``make_mesh`` builds a mesh whose axes are all ``Auto`` (the compiler
+places collectives); install one with ``jax.set_mesh``.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_mesh_compat", "use_mesh",
-           "POD_SHAPE", "MULTIPOD_SHAPE"]
+__all__ = ["make_production_mesh", "make_mesh", "POD_SHAPE",
+           "MULTIPOD_SHAPE"]
 
 POD_SHAPE = (16, 16)                 # 256 chips (one v5e pod slice)
 MULTIPOD_SHAPE = (2, 16, 16)         # 2 pods = 512 chips
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types on any supported jax."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the current mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # old jax: Mesh is itself the context manager
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTIPOD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)

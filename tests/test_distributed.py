@@ -21,7 +21,7 @@ from repro.distributed.sharding import (  # noqa: E402
     cache_shardings,
     param_shardings,
 )
-from repro.launch.mesh import make_mesh_compat, use_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import build  # noqa: E402
 
 pytestmark = pytest.mark.skipif(
@@ -29,7 +29,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh():
-    return make_mesh_compat((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def test_param_shardings_cover_tree():
@@ -58,7 +58,7 @@ def test_sharded_train_step_runs():
     cfg = reduced(get_config("minitron-4b"))
     mesh = _mesh()
     model, train_step = make_train_step(cfg)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         shapes = jax.eval_shape(lambda: params)
         p_sh = param_shardings(shapes, mesh, cfg.n_experts)
@@ -126,7 +126,7 @@ def test_grad_compression_training_parity():
 
 
 def test_pipeline_matches_sequential():
-    mesh = make_mesh_compat((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     rng = np.random.default_rng(2)
     n_stages, n_micro, mb, d = 4, 8, 4, 16
     ws = jnp.asarray(rng.standard_normal((n_stages, d, d)) * 0.3)
@@ -136,7 +136,7 @@ def test_pipeline_matches_sequential():
 
     x = jnp.asarray(rng.standard_normal((n_micro, mb, d)))
     piped = pipeline_apply(stage_fn, n_stages, n_micro, mesh, axis="pod")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = piped(ws, x)
     ref = x
     for s in range(n_stages):
@@ -149,7 +149,7 @@ def test_sp_decode_matches_dense():
     """Flash-decoding shard_map == dense attention over the gathered cache."""
     from repro.distributed.sp import make_sp_decode
 
-    mesh = make_mesh_compat((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     rng = np.random.default_rng(3)
     B, T, H, KV, D = 2, 64, 8, 4, 16
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
@@ -166,7 +166,7 @@ def test_sp_decode_matches_dense():
     p = jax.nn.softmax(scores, axis=-1)
     ref = jnp.einsum("bkgt,btkd->bkgd", p, v).reshape(B, 1, H, D)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = make_sp_decode(mesh)(q, k, v, valid)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -181,8 +181,8 @@ def test_elastic_reshard_across_meshes():
 
     cfg = reduced(get_config("gemma3-1b"))
     model = build(cfg)
-    mesh_a = make_mesh_compat((2, 4), ("data", "model"))
-    mesh_b = make_mesh_compat((4, 2), ("data", "model"))
+    mesh_a = make_mesh((2, 4), ("data", "model"))
+    mesh_b = make_mesh((4, 2), ("data", "model"))
     params = model.init(jax.random.PRNGKey(0))
     shapes = jax.eval_shape(lambda: params)
     with tempfile.TemporaryDirectory() as d:
