@@ -14,6 +14,7 @@ import dataclasses
 import numpy as np
 
 from .kmeans import assign, kmeans
+from .stats import span
 
 __all__ = ["ProductQuantizer"]
 
@@ -59,10 +60,11 @@ class ProductQuantizer:
         nq, d = queries.shape
         dsub = d // self.m
         tabs = np.zeros((nq, self.m, self.ksub), np.float32)
-        for j in range(self.m):
-            qs = queries[:, j * dsub : (j + 1) * dsub]
-            diff = qs[:, None, :] - self.codebooks[j][None]
-            tabs[:, j] = np.einsum("qkd,qkd->qk", diff, diff)
+        with span("pq.adc_tables"):
+            for j in range(self.m):
+                qs = queries[:, j * dsub : (j + 1) * dsub]
+                diff = qs[:, None, :] - self.codebooks[j][None]
+                tabs[:, j] = np.einsum("qkd,qkd->qk", diff, diff)
         return tabs
 
     @staticmethod

@@ -42,12 +42,15 @@ Only the stats differ.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from collections import OrderedDict
 from typing import Callable, Dict, List
 
 import numpy as np
+
+from .stats import SearchStats, span
 
 __all__ = [
     "batched_search",
@@ -156,6 +159,7 @@ class DecodedListCache:
         self.bytes = 0
         self.hits = 0
         self.decodes = 0
+        self.decode_s = 0.0            # lifetime seconds inside decode()
         self.evictions = 0
         self.promotions = 0
 
@@ -199,7 +203,10 @@ class DecodedListCache:
             else:
                 self._lists.move_to_end(key)
             return hit
-        arr = np.asarray(decode())
+        t0 = time.perf_counter()
+        with span("ids.decode"):
+            arr = np.asarray(decode())
+        self.decode_s += time.perf_counter() - t0
         self.decodes += 1
         self._lists[key] = arr
         self.bytes += arr.nbytes
@@ -229,12 +236,13 @@ class DecodedListCache:
         if self.policy == "2q":
             self._shrink_hot()
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, float]:
         out = {
             "entries": len(self),
             "bytes": self.bytes,
             "hits": self.hits,
             "decodes": self.decodes,
+            "decode_s": self.decode_s,
             "evictions": self.evictions,
         }
         if self.policy == "2q":
@@ -486,6 +494,54 @@ def pack_merge_keys(ranks: np.ndarray, offs: np.ndarray) -> np.ndarray:
     return (ranks << np.uint64(MERGE_KEY_OFFSET_BITS)) | offs
 
 
+class _Stages:
+    """Timers, upload bytes and compile counts of one ``batched_search``.
+
+    Each stage runs under a profiler span of its name (``scan.<stage>``)
+    and adds its ``perf_counter`` time to ``seconds``; ``put`` counts the
+    bytes it hands to the device; ``dispatch`` puts a ``scan.compile``
+    span around a program whose static signature this process has not
+    dispatched before.
+    """
+
+    def __init__(self, jnp):
+        self.jnp = jnp
+        self.seconds = {"arena": 0.0, "upload": 0.0, "select": 0.0,
+                        "rescore": 0.0}
+        self.upload_bytes = 0
+        self.new_shapes = 0
+
+    @contextlib.contextmanager
+    def stage(self, name: str):
+        t0 = time.perf_counter()
+        with span(f"scan.{name}"):
+            yield
+        self.seconds[name] += time.perf_counter() - t0
+
+    def put(self, a: np.ndarray):
+        self.upload_bytes += a.nbytes
+        return self.jnp.asarray(a)
+
+    def dispatch(self, signature: tuple, fn, *args, **kwargs):
+        if signature in _DISPATCHED:
+            return fn(*args, **kwargs)
+        with span("scan.compile"):
+            out = fn(*args, **kwargs)
+        _DISPATCHED.add(signature)
+        self.new_shapes += 1
+        return out
+
+
+# static signatures ``(kind, qb_pad, u_pad, P, c_pad_b, K, engine)`` of the
+# scorer and device-select programs dispatched so far (``kind`` names the
+# program and its fixed widths: ``flat:<d>``, ``pq:<m>:<dtype>``,
+# ``select:<nlist>``; a scorer has no P, c_pad_b or K and gives 0).  jit
+# caches are process-wide, so the set is too: a signature missing here
+# traces and compiles (or loads from the persistent cache) inside its
+# dispatch.
+_DISPATCHED: set = set()
+
+
 def batched_search(index, queries: np.ndarray, nprobe: int = 16,
                    topk: int = 10, engine: str = "auto",
                    query_block: int = DEFAULT_QUERY_BLOCK,
@@ -517,9 +573,14 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
     sharded router that merges per-shard results by ``(dist, key)``
     reproduces the unsharded output bit-for-bit even under duplicate
     vectors (repro.shard.service).
+
+    Each stage runs under a profiler span (``scan.search`` around the
+    call; ``scan.coarse_probes``, ``scan.arena``, ``scan.upload``,
+    ``scan.score``, ``scan.select``, ``scan.rescore`` and ``ids.resolve``
+    inside it; ``scan.compile`` around a first dispatch) and is timed
+    into the returned stats whether or not a profiler runs.
     """
     from .pq import ProductQuantizer
-    from .stats import SearchStats
 
     jnp = _jax().numpy
     engine = _resolve_engine(engine)
@@ -527,252 +588,291 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
         raise ValueError(f"unknown select mode {select!r} "
                          "(options: auto, host, device)")
     t0 = time.perf_counter()
-    queries = np.asarray(queries)
-    nq = queries.shape[0]
-    all_ids = np.zeros((nq, topk), np.int64)
-    all_d = np.full((nq, topk), np.inf, np.float32)
-    probes = coarse_probes(queries, index.centroids, nprobe)
-    tables = index.pq.adc_tables(queries) if index.pq is not None else None
-    use_pq = index.pq is not None
-    interpret = _jax().default_backend() == "cpu"
-    if select_min is None:
-        select_min = SELECT_MIN_CPU if interpret else 1
-
-    offsets, sizes = index.offsets, index.sizes
-    ndis = 0
-    nbatches = 0
-    host_block_bytes = 0
-    n_dev_select = 0
-    distinct: set = set()
-    decodes_before = index.decoded_cache.decodes
-    # winning (cluster, offset) pairs across the whole call, resolved in one
-    # pass at the end
-    res_q: List[np.ndarray] = []
-    res_slot: List[np.ndarray] = []
-    res_cluster: List[np.ndarray] = []
-    res_offset: List[np.ndarray] = []
-    res_key: List[np.ndarray] = []
-    all_keys = (np.full((nq, topk), MERGE_KEY_PAD, np.uint64)
-                if with_keys else None)
-
-    for q0 in range(0, nq, query_block):
-        q1 = min(nq, q0 + query_block)
-        qb = q1 - q0
-        nbatches += 1
-        blk_probes = probes[q0:q1]
-        # --- dedup probed clusters; build the arena ------------------------
-        uniq = np.unique(blk_probes)
-        uniq_sizes = sizes[uniq].astype(np.int64)
-        keep = uniq_sizes > 0
-        uniq, uniq_sizes = uniq[keep], uniq_sizes[keep]
-        distinct.update(int(k) for k in uniq)
-        arena_start = np.cumsum(uniq_sizes) - uniq_sizes
-        u_rows = int(uniq_sizes.sum())
-        arena_rows = _spans_concat(offsets[uniq], uniq_sizes)
-        # cluster id -> arena span start (dense map over probed ids only)
-        start_of = np.full(index.nlist, -1, dtype=np.int64)
-        size_of = np.zeros(index.nlist, dtype=np.int64)
-        start_of[uniq] = arena_start
-        size_of[uniq] = uniq_sizes
-        if with_keys:
-            # probe rank of each cluster per query (same for every shard of a
-            # shared-quantizer plan, since probes only depend on centroids)
-            rank_of = np.zeros((qb, index.nlist), np.uint64)
-            rank_of[np.arange(qb)[:, None], blk_probes] = np.arange(
-                blk_probes.shape[1], dtype=np.uint64)[None]
-
-        # --- per-query padded candidate rows (probe order == oracle order) -
-        pp_sizes = size_of[blk_probes]              # (qb, P)
-        cand_lens = pp_sizes.sum(axis=1)
-        ndis += int(cand_lens.sum())
-        c_pad = int(cand_lens.max()) if qb else 0
-        if c_pad == 0:
-            continue
-        flat_pos = _spans_concat(start_of[blk_probes].ravel(),
-                                 pp_sizes.ravel())
-        cand_pos = np.full((qb, c_pad), -1, dtype=np.int64)
-        row_ids = np.repeat(np.arange(qb), cand_lens)
-        col_ids = np.concatenate(
-            [np.arange(c) for c in cand_lens]
-        ) if qb else np.zeros(0, np.int64)
-        cand_pos[row_ids, col_ids] = flat_pos
-
-        # --- blocked scoring ----------------------------------------------
-        # bucketed padding (not fixed query_block): a max-wait flush of a few
-        # queries must not score query_block-worth of phantom LUTs/rows
-        u_pad = _bucket(u_rows)
-        qb_pad = _bucket(qb, floor=8)
+    with span("scan.search"):
+        queries = np.asarray(queries)
+        nq = queries.shape[0]
+        all_ids = np.zeros((nq, topk), np.int64)
+        all_d = np.full((nq, topk), np.inf, np.float32)
+        with span("scan.coarse_probes"):
+            probes = coarse_probes(queries, index.centroids, nprobe)
+        tables = index.pq.adc_tables(queries) if index.pq is not None else None
+        use_pq = index.pq is not None
+        interpret = _jax().default_backend() == "cpu"
+        if select_min is None:
+            select_min = SELECT_MIN_CPU if interpret else 1
         if use_pq:
-            arena = np.zeros((u_pad, index.codes.shape[1]),
-                             index.codes.dtype)
-            arena[:u_rows] = index.codes[arena_rows]
-            luts = np.zeros((qb_pad,) + tables.shape[1:], np.float32)
-            luts[:qb] = tables[q0:q1]
             scorer = _adc_scorers()[engine]
-            if engine == "pallas":
-                dmat = scorer(jnp.asarray(luts), jnp.asarray(arena),
-                              interpret=interpret)
-            else:
-                dmat = scorer(jnp.asarray(luts), jnp.asarray(arena))
+            score_kind = f"pq:{index.codes.shape[1]}:{index.codes.dtype}"
         else:
-            arena = np.zeros((u_pad, index.d), np.float32)
-            arena[:u_rows] = index.vecs[arena_rows]
-            qblk = np.zeros((qb_pad, index.d), np.float32)
-            qblk[:qb] = queries[q0:q1]
             scorer = _flat_scorers()[engine]
-            if engine == "pallas":
-                dmat = scorer(jnp.asarray(qblk), jnp.asarray(arena),
-                              interpret=interpret)
-            else:
-                dmat = scorer(jnp.asarray(qblk), jnp.asarray(arena))
-        if not use_pq:
-            qn_host = np.einsum("qd,qd->q",
-                                queries[q0:q1].astype(np.float32),
-                                queries[q0:q1].astype(np.float32))
+            score_kind = f"flat:{index.d}"
+        score_kw = {"interpret": interpret} if engine == "pallas" else {}
 
-        def finish(i, qi, pos):
-            # exact re-score of one query's short-list; ``pos`` holds the
-            # selected arena positions in candidate (oracle concat) order,
-            # so select_topk's stable tie-break reproduces the oracle's.
-            rows = arena_rows[pos]
-            if use_pq:
-                d_exact = ProductQuantizer.adc_score(
-                    index.codes[rows], tables[qi])
-            else:
-                d_exact = score_rows_flat(index.vecs[rows], queries[qi])
-            best = select_topk(d_exact, topk)
-            n_found = best.shape[0]
-            all_d[qi, :n_found] = d_exact[best]
-            # (cluster, offset) from arena position
-            p = pos[best]
-            span = np.searchsorted(arena_start, p, side="right") - 1
-            res_q.append(np.full(n_found, qi, np.int64))
-            res_slot.append(np.arange(n_found, dtype=np.int64))
-            res_cluster.append(uniq[span])
-            res_offset.append(p - arena_start[span])
-            if with_keys:
-                res_key.append(pack_merge_keys(rank_of[i, uniq[span]],
-                                               p - arena_start[span]))
+        offsets, sizes = index.offsets, index.sizes
+        ndis = 0
+        nbatches = 0
+        host_block_bytes = 0
+        n_dev_select = 0
+        select_calls = 0
+        stages = _Stages(jnp)
+        distinct: set = set()
+        cache = index.decoded_cache
+        decodes_before, decode_s_before = cache.decodes, cache.decode_s
+        # winning (cluster, offset) pairs across the whole call, resolved in
+        # one pass at the end
+        res_q: List[np.ndarray] = []
+        res_slot: List[np.ndarray] = []
+        res_cluster: List[np.ndarray] = []
+        res_offset: List[np.ndarray] = []
+        res_key: List[np.ndarray] = []
+        all_keys = (np.full((nq, topk), MERGE_KEY_PAD, np.uint64)
+                    if with_keys else None)
 
-        if _resolve_select(select, c_pad, select_min):
-            # --- device-side segmented top-k -------------------------------
-            # the (qb, C_pad) block stays on device: a jitted gather +
-            # seg_topk returns (qb, K) shortlist values / candidate columns
-            # / arena positions, the host recomputes the SAME short-list
-            # threshold the host path uses (bound of the take-th smallest
-            # kernel value + rescore_eps, in float64 over identical f32
-            # values), and K doubles while any row's shortlist might extend
-            # past it — so the cut set matches the host path exactly.
-            n_dev_select += 1
-            runner = _device_selector()
-            c_pad_b = _bucket(c_pad, floor=128)
-            probes_pad = np.zeros((qb_pad, blk_probes.shape[1]), np.int32)
-            probes_pad[:qb] = blk_probes
-            start32 = np.maximum(start_of, 0).astype(np.int32)
-            size32 = size_of.astype(np.int32)
-            K = min(_bucket(min(topk + RESCORE_SLACK, c_pad), floor=16),
-                    c_pad_b)
-            while True:
-                vals_d, cols_d, pos_d = runner(
-                    dmat, jnp.asarray(probes_pad), jnp.asarray(start32),
-                    jnp.asarray(size32), c_pad=c_pad_b, k=K, engine=engine,
-                    interpret=interpret)
-                vals = np.asarray(vals_d)
-                sel_cols = np.asarray(cols_d)
-                sel_pos = np.asarray(pos_d)
-                host_block_bytes += (vals.nbytes + sel_cols.nbytes
-                                     + sel_pos.nbytes)
-                vals = vals[:qb]
-                thr = np.full(qb, -np.inf)
-                retry = False
-                for i in range(qb):
-                    nvalid = int(cand_lens[i])
-                    if nvalid == 0:
-                        continue
-                    take = min(topk + RESCORE_SLACK, nvalid)
-                    bound = float(vals[i, take - 1])
-                    eps = rescore_eps(index.d, bound,
-                                      0.0 if use_pq else float(qn_host[i]))
-                    thr[i] = bound + eps
-                    if nvalid > K and vals[i, K - 1] <= thr[i]:
-                        retry = True    # band may extend past the K cut
-                if not retry or K >= c_pad_b:
-                    break
-                K = min(2 * K, c_pad_b)
-            for i in range(qb):
-                qi = q0 + i
-                nvalid = int(cand_lens[i])
-                if nvalid == 0:
+        for q0 in range(0, nq, query_block):
+            q1 = min(nq, q0 + query_block)
+            qb = q1 - q0
+            nbatches += 1
+            blk_probes = probes[q0:q1]
+            with stages.stage("arena"):
+                # --- dedup probed clusters; build the arena ----------------
+                uniq = np.unique(blk_probes)
+                uniq_sizes = sizes[uniq].astype(np.int64)
+                keep = uniq_sizes > 0
+                uniq, uniq_sizes = uniq[keep], uniq_sizes[keep]
+                distinct.update(int(k) for k in uniq)
+                arena_start = np.cumsum(uniq_sizes) - uniq_sizes
+                u_rows = int(uniq_sizes.sum())
+                arena_rows = _spans_concat(offsets[uniq], uniq_sizes)
+                # cluster id -> arena span start (dense map over probed ids)
+                start_of = np.full(index.nlist, -1, dtype=np.int64)
+                size_of = np.zeros(index.nlist, dtype=np.int64)
+                start_of[uniq] = arena_start
+                size_of[uniq] = uniq_sizes
+                if with_keys:
+                    # probe rank of each cluster per query (same for every
+                    # shard of a shared-quantizer plan, since probes only
+                    # depend on centroids)
+                    rank_of = np.zeros((qb, index.nlist), np.uint64)
+                    rank_of[np.arange(qb)[:, None], blk_probes] = np.arange(
+                        blk_probes.shape[1], dtype=np.uint64)[None]
+
+                # --- per-query padded candidate rows (probe order == oracle)
+                pp_sizes = size_of[blk_probes]              # (qb, P)
+                cand_lens = pp_sizes.sum(axis=1)
+                ndis += int(cand_lens.sum())
+                c_pad = int(cand_lens.max()) if qb else 0
+                if c_pad == 0:
                     continue
-                # vals are ascending: count the entries inside the band,
-                # drop padding columns (>= nvalid; real +inf hits keep
-                # their column < nvalid), restore oracle concat order
-                cnt = int(np.searchsorted(vals[i], thr[i], side="right"))
-                cc, pp_sel = sel_cols[i, :cnt], sel_pos[i, :cnt]
-                real = cc < nvalid
-                cc, pp_sel = cc[real], pp_sel[real]
-                finish(i, qi, pp_sel[np.argsort(cc)].astype(np.int64))
-            continue
+                flat_pos = _spans_concat(start_of[blk_probes].ravel(),
+                                         pp_sizes.ravel())
+                cand_pos = np.full((qb, c_pad), -1, dtype=np.int64)
+                row_ids = np.repeat(np.arange(qb), cand_lens)
+                col_ids = np.concatenate(
+                    [np.arange(c) for c in cand_lens]
+                ) if qb else np.zeros(0, np.int64)
+                cand_pos[row_ids, col_ids] = flat_pos
 
-        # --- host-side stable top-k over the pulled padded block -----------
-        dmat = np.asarray(dmat)
-        host_block_bytes += dmat.nbytes
-        dmat = dmat[:qb]
-        safe_pos = np.clip(cand_pos, 0, max(0, u_pad - 1))
-        d_blk = np.where(
-            cand_pos >= 0,
-            np.take_along_axis(dmat, safe_pos, axis=1),
-            np.inf,
-        ).astype(np.float32)
-        order = np.argsort(d_blk, axis=1, kind="stable")
-        for i in range(qb):
-            qi = q0 + i
-            nvalid = int(cand_lens[i])
-            take = min(topk + RESCORE_SLACK, nvalid)
-            if take == 0:
-                continue
-            # kernel distances only have to get the top-k *set* right.  The
-            # expanded qn-2qc+cn form cancels catastrophically for
-            # near-duplicate vectors, so candidates near the shortlist
-            # boundary may be mis-ranked by up to the cancellation error —
-            # extend the shortlist through that error band so the exact
-            # re-score below sees every potential top-k member.
-            row = d_blk[i]
-            bound = float(row[order[i, take - 1]])
-            eps = rescore_eps(index.d, bound,
-                              0.0 if use_pq else float(qn_host[i]))
-            while take < nvalid and row[order[i, take]] <= bound + eps:
-                take += 1
-            # candidate *row positions* are the oracle's concat positions:
-            # sorting them restores the oracle's stable tie order.
-            sel = np.sort(order[i, :take])
-            finish(i, qi, cand_pos[i, sel])
+                # bucketed padding (not fixed query_block): a max-wait flush
+                # of a few queries must not score query_block-worth of
+                # phantom LUTs/rows
+                u_pad = _bucket(u_rows)
+                qb_pad = _bucket(qb, floor=8)
+                if use_pq:
+                    arena = np.zeros((u_pad, index.codes.shape[1]),
+                                     index.codes.dtype)
+                    arena[:u_rows] = index.codes[arena_rows]
+                    lhs = np.zeros((qb_pad,) + tables.shape[1:], np.float32)
+                    lhs[:qb] = tables[q0:q1]
+                else:
+                    arena = np.zeros((u_pad, index.d), np.float32)
+                    arena[:u_rows] = index.vecs[arena_rows]
+                    lhs = np.zeros((qb_pad, index.d), np.float32)
+                    lhs[:qb] = queries[q0:q1]
+                    qn_host = np.einsum("qd,qd->q",
+                                        queries[q0:q1].astype(np.float32),
+                                        queries[q0:q1].astype(np.float32))
 
-    # --- late id resolution: one pass over every winning pair --------------
-    t_res = time.perf_counter()
-    if res_q:
-        rq = np.concatenate(res_q)
-        rs = np.concatenate(res_slot)
-        ids = resolve_ids_batch(
-            index, np.concatenate(res_cluster), np.concatenate(res_offset))
-        all_ids[rq, rs] = ids
-        if with_keys:
-            all_keys[rq, rs] = np.concatenate(res_key)
-    resolve_s = time.perf_counter() - t_res
-    index._last_resolve_s = resolve_s
+            # --- blocked scoring (lhs: the query block or its LUTs) --------
+            with stages.stage("upload"):
+                lhs_d, arena_d = stages.put(lhs), stages.put(arena)
+            with span("scan.score"):
+                dmat = stages.dispatch(
+                    (score_kind, qb_pad, u_pad, 0, 0, 0, engine), scorer,
+                    lhs_d, arena_d, **score_kw)
 
-    stats = SearchStats(
-        wall_s=time.perf_counter() - t0,
-        ndis=ndis,
-        id_resolve_s=resolve_s,
-        decodes=index.decoded_cache.decodes - decodes_before,
-        distinct_probed=len(distinct),
-        batches=nbatches,
-        engine=engine,
-        host_block_bytes=host_block_bytes,
-        device_select=n_dev_select,
-        merge_keys=all_keys,
-    )
+            def finish(i, qi, pos):
+                # exact re-score of one query's short-list; ``pos`` holds the
+                # selected arena positions in candidate (oracle concat)
+                # order, so select_topk's stable tie-break reproduces the
+                # oracle's.
+                rows = arena_rows[pos]
+                if use_pq:
+                    d_exact = ProductQuantizer.adc_score(
+                        index.codes[rows], tables[qi])
+                else:
+                    d_exact = score_rows_flat(index.vecs[rows], queries[qi])
+                best = select_topk(d_exact, topk)
+                n_found = best.shape[0]
+                all_d[qi, :n_found] = d_exact[best]
+                # (cluster, offset) from arena position
+                p = pos[best]
+                span_of = np.searchsorted(arena_start, p, side="right") - 1
+                res_q.append(np.full(n_found, qi, np.int64))
+                res_slot.append(np.arange(n_found, dtype=np.int64))
+                res_cluster.append(uniq[span_of])
+                res_offset.append(p - arena_start[span_of])
+                if with_keys:
+                    res_key.append(pack_merge_keys(
+                        rank_of[i, uniq[span_of]], p - arena_start[span_of]))
+
+            if _resolve_select(select, c_pad, select_min):
+                # --- device-side segmented top-k ---------------------------
+                # the (qb, C_pad) block stays on device: a jitted gather +
+                # seg_topk returns (qb, K) shortlist values / candidate
+                # columns / arena positions, the host recomputes the SAME
+                # short-list threshold the host path uses (bound of the
+                # take-th smallest kernel value + rescore_eps, in float64
+                # over identical f32 values), and K doubles while any row's
+                # shortlist might extend past it — so the cut set matches
+                # the host path exactly.
+                n_dev_select += 1
+                with stages.stage("select"):
+                    runner = _device_selector()
+                    c_pad_b = _bucket(c_pad, floor=128)
+                    probes_pad = np.zeros((qb_pad, blk_probes.shape[1]),
+                                          np.int32)
+                    probes_pad[:qb] = blk_probes
+                    start32 = np.maximum(start_of, 0).astype(np.int32)
+                    size32 = size_of.astype(np.int32)
+                    K = min(_bucket(min(topk + RESCORE_SLACK, c_pad),
+                                    floor=16), c_pad_b)
+                    while True:
+                        select_calls += 1
+                        vals_d, cols_d, pos_d = stages.dispatch(
+                            (f"select:{index.nlist}", qb_pad, u_pad,
+                             blk_probes.shape[1], c_pad_b, K, engine),
+                            runner, dmat, stages.put(probes_pad),
+                            stages.put(start32), stages.put(size32),
+                            c_pad=c_pad_b, k=K, engine=engine,
+                            interpret=interpret)
+                        vals = np.asarray(vals_d)
+                        sel_cols = np.asarray(cols_d)
+                        sel_pos = np.asarray(pos_d)
+                        host_block_bytes += (vals.nbytes + sel_cols.nbytes
+                                             + sel_pos.nbytes)
+                        vals = vals[:qb]
+                        thr = np.full(qb, -np.inf)
+                        retry = False
+                        for i in range(qb):
+                            nvalid = int(cand_lens[i])
+                            if nvalid == 0:
+                                continue
+                            take = min(topk + RESCORE_SLACK, nvalid)
+                            bound = float(vals[i, take - 1])
+                            eps = rescore_eps(
+                                index.d, bound,
+                                0.0 if use_pq else float(qn_host[i]))
+                            thr[i] = bound + eps
+                            if nvalid > K and vals[i, K - 1] <= thr[i]:
+                                retry = True  # band may extend past the cut
+                        if not retry or K >= c_pad_b:
+                            break
+                        K = min(2 * K, c_pad_b)
+                with stages.stage("rescore"):
+                    for i in range(qb):
+                        qi = q0 + i
+                        nvalid = int(cand_lens[i])
+                        if nvalid == 0:
+                            continue
+                        # vals are ascending: count the entries inside the
+                        # band, drop padding columns (>= nvalid; real +inf
+                        # hits keep their column < nvalid), restore oracle
+                        # concat order
+                        cnt = int(np.searchsorted(vals[i], thr[i],
+                                                  side="right"))
+                        cc, pp_sel = sel_cols[i, :cnt], sel_pos[i, :cnt]
+                        real = cc < nvalid
+                        cc, pp_sel = cc[real], pp_sel[real]
+                        finish(i, qi, pp_sel[np.argsort(cc)].astype(np.int64))
+            else:
+                # --- host-side stable top-k over the pulled padded block ---
+                with stages.stage("select"):
+                    dmat = np.asarray(dmat)
+                    host_block_bytes += dmat.nbytes
+                    dmat = dmat[:qb]
+                    safe_pos = np.clip(cand_pos, 0, max(0, u_pad - 1))
+                    d_blk = np.where(
+                        cand_pos >= 0,
+                        np.take_along_axis(dmat, safe_pos, axis=1),
+                        np.inf,
+                    ).astype(np.float32)
+                    order = np.argsort(d_blk, axis=1, kind="stable")
+                with stages.stage("rescore"):
+                    for i in range(qb):
+                        qi = q0 + i
+                        nvalid = int(cand_lens[i])
+                        take = min(topk + RESCORE_SLACK, nvalid)
+                        if take == 0:
+                            continue
+                        # kernel distances only have to get the top-k
+                        # *set* right.  The expanded qn-2qc+cn form cancels
+                        # catastrophically for near-duplicate vectors, so
+                        # candidates near the shortlist boundary may be
+                        # mis-ranked by up to the cancellation error —
+                        # extend the shortlist through that error band so
+                        # the exact re-score below sees every potential
+                        # top-k member.
+                        row = d_blk[i]
+                        bound = float(row[order[i, take - 1]])
+                        eps = rescore_eps(
+                            index.d, bound,
+                            0.0 if use_pq else float(qn_host[i]))
+                        while (take < nvalid
+                               and row[order[i, take]] <= bound + eps):
+                            take += 1
+                        # candidate *row positions* are the oracle's concat
+                        # positions: sorting them restores the oracle's
+                        # stable tie order.
+                        sel = np.sort(order[i, :take])
+                        finish(i, qi, cand_pos[i, sel])
+            with stages.stage("arena"):
+                # release the block's arena on the host and the device
+                # inside the stage, not at the next fill or the return
+                del arena, lhs, arena_d, lhs_d, dmat
+
+        # --- late id resolution: one pass over every winning pair ----------
+        t_res = time.perf_counter()
+        with span("ids.resolve"):
+            if res_q:
+                rq = np.concatenate(res_q)
+                rs = np.concatenate(res_slot)
+                ids = resolve_ids_batch(index, np.concatenate(res_cluster),
+                                        np.concatenate(res_offset))
+                all_ids[rq, rs] = ids
+                if with_keys:
+                    all_keys[rq, rs] = np.concatenate(res_key)
+        resolve_s = time.perf_counter() - t_res
+
+        stats = SearchStats(
+            wall_s=time.perf_counter() - t0,
+            ndis=ndis,
+            id_resolve_s=resolve_s,
+            decodes=cache.decodes - decodes_before,
+            distinct_probed=len(distinct),
+            batches=nbatches,
+            engine=engine,
+            host_block_bytes=host_block_bytes,
+            device_select=n_dev_select,
+            arena_s=stages.seconds["arena"],
+            upload_s=stages.seconds["upload"],
+            select_s=stages.seconds["select"],
+            rescore_s=stages.seconds["rescore"],
+            decode_s=cache.decode_s - decode_s_before,
+            upload_bytes=stages.upload_bytes,
+            select_calls=select_calls,
+            new_shapes=stages.new_shapes,
+            merge_keys=all_keys,
+        )
     return all_ids, all_d, stats
 
 
@@ -821,8 +921,6 @@ def batched_flat_search(vecs: np.ndarray, queries: np.ndarray,
     Returns ``(ids (nq, topk) int64, dists (nq, topk) f32, SearchStats)``
     with ``engine="flat-pallas"`` / ``"flat-xla"``.
     """
-    from .stats import SearchStats
-
     jnp = _jax().numpy
     engine = _resolve_engine(engine)
     interpret = _jax().default_backend() == "cpu"

@@ -12,14 +12,39 @@ scattered batch (wall time is the *max* across shards — they run in
 parallel) and the fault layer fills ``shards`` / ``shards_failed`` /
 ``partial`` / ``retries`` so a degraded answer is visible in-band
 instead of as an exception.
+
+:func:`span` names a stage of the search in the JAX profiler's trace.
+The IVF engine times the same stages into ``SearchStats`` (``arena_s``,
+``upload_s``, ``select_s``, ``rescore_s``, ``decode_s``), so the per-call
+split is recorded whether or not a profiler runs.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import sys
 from typing import Optional, Sequence
 
-__all__ = ["SearchStats", "combine_stats"]
+__all__ = ["SearchStats", "combine_stats", "span"]
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **meta):
+    """A host span ``name`` in the JAX profiler's trace, tagged with ``meta``.
+
+    ``jax.profiler.TraceAnnotation`` when jax is already imported (it
+    writes on the clock of the device ops, so a trace charges each idle
+    device gap to the innermost span over it; without a running profiler
+    it records nothing), else a shared no-op context: numpy-only use of an
+    index never imports jax.  Never use it inside a jitted function: it
+    would time the trace, not the run.
+    """
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_SPAN
+    return jax.profiler.TraceAnnotation(name, **meta)
 
 
 @dataclasses.dataclass
@@ -42,6 +67,17 @@ class SearchStats:
     # materialized host-side when device_select covers every block/step
     host_block_bytes: int = 0
     device_select: int = 0     # query blocks / graph steps selected on device
+    # -- stages of the batched IVF scan (0 elsewhere); each ``*_s`` is the
+    # perf_counter time of the profiler span of the same stage, summed over
+    # the call's query blocks -------------------------------------------------
+    arena_s: float = 0.0       # scan.arena: dedup, arena fill and release
+    upload_s: float = 0.0      # scan.upload: host->device copy of the arena
+    select_s: float = 0.0      # scan.select: top-k cut incl. device wait
+    rescore_s: float = 0.0     # scan.rescore: exact host re-score
+    decode_s: float = 0.0      # ids.decode: id-list decodes (in id_resolve_s)
+    upload_bytes: int = 0      # bytes handed to the device (retries included)
+    select_calls: int = 0      # device-select runs incl. K-doubling retries
+    new_shapes: int = 0        # scorer/select signatures first seen this call
     # -- sharded-serving aggregation (repro.shard) ---------------------------
     shards: int = 0            # shards scattered to (0 = unsharded call)
     shards_failed: int = 0     # shards that missed the deadline / died
@@ -78,5 +114,13 @@ def combine_stats(parts: Sequence[SearchStats], *, wall_s: float,
         out.dedup_hits += s.dedup_hits
         out.host_block_bytes += s.host_block_bytes
         out.device_select += s.device_select
+        out.arena_s += s.arena_s
+        out.upload_s += s.upload_s
+        out.select_s += s.select_s
+        out.rescore_s += s.rescore_s
+        out.decode_s += s.decode_s
+        out.upload_bytes += s.upload_bytes
+        out.select_calls += s.select_calls
+        out.new_shapes += s.new_shapes
         out.retries += s.retries
     return out

@@ -43,7 +43,13 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..ann.stats import span
+
 __all__ = ["AnnService", "AddTicket", "BatchPolicy", "Ticket"]
+
+# SearchStats fields of the batched IVF scan's stages, summed into stats()
+SCAN_STAGE_KEYS = ("arena_s", "upload_s", "select_s", "rescore_s",
+                   "decode_s", "upload_bytes", "select_calls", "new_shapes")
 
 
 @dataclasses.dataclass
@@ -140,6 +146,8 @@ class AnnService:
         self.resolve_s = 0.0
         self.host_block_bytes = 0
         self.device_selects = 0
+        # stages of the IVF scan (SearchStats fields of the same names)
+        self.scan_stages = dict.fromkeys(SCAN_STAGE_KEYS, 0)
         self.last_stats = None         # SearchStats of the most recent flush
         # bounded: long-lived replicas must not grow per-request state
         self._batch_sizes: "deque[int]" = deque(maxlen=4096)
@@ -246,6 +254,10 @@ class AnnService:
         self.flush_adds()
         if not self._pending:
             return []
+        with span("serve.flush", batch_id=self.batches):
+            return self._flush()
+
+    def _flush(self) -> List[Ticket]:
         tickets, self._pending = self._pending, []
         qs, self._pending_q = self._pending_q, []
         now = self.clock()
@@ -262,6 +274,8 @@ class AnnService:
         self.resolve_s += st.id_resolve_s
         self.host_block_bytes += getattr(st, "host_block_bytes", 0)
         self.device_selects += getattr(st, "device_select", 0)
+        for key in SCAN_STAGE_KEYS:
+            self.scan_stages[key] += getattr(st, key, 0)
         self._batch_sizes.append(batch.shape[0])
         row = 0
         for t in tickets:
@@ -316,6 +330,13 @@ class AnnService:
           ledger: bytes of device-computed distance data pulled to the
           host, and query blocks / graph steps whose top-k cut ran on
           device (``repro.kernels.seg_topk``).
+        * ``arena_s`` / ``upload_s`` / ``select_s`` / ``rescore_s`` /
+          ``decode_s`` — cumulative seconds of the IVF scan's stages
+          (arena gather, host->device copy, top-k cut incl. the device
+          wait, exact re-score, id-list decodes inside ``resolve_s``).
+        * ``upload_bytes`` / ``select_calls`` / ``new_shapes`` — bytes
+          copied to the device, device-select runs (K-doubling retries
+          included) and first-seen scorer/select signatures (compiles).
         """
         bs = np.asarray(self._batch_sizes, np.float64)
         ws = np.asarray(self._waits, np.float64)
@@ -341,6 +362,7 @@ class AnnService:
             "decodes": self.decodes,
             "host_block_bytes": self.host_block_bytes,
             "device_selects": self.device_selects,
+            **self.scan_stages,
         }
 
     def memory_ledger(self) -> Dict[str, float]:
