@@ -160,6 +160,7 @@ class DecodedListCache:
         self.hits = 0
         self.decodes = 0
         self.decode_s = 0.0            # lifetime seconds inside decode()
+        self.decode_ids = 0            # lifetime ids those decodes produced
         self.evictions = 0
         self.promotions = 0
 
@@ -207,6 +208,7 @@ class DecodedListCache:
         with span("ids.decode"):
             arr = np.asarray(decode())
         self.decode_s += time.perf_counter() - t0
+        self.decode_ids += len(arr)
         self.decodes += 1
         self._lists[key] = arr
         self.bytes += arr.nbytes
@@ -243,6 +245,7 @@ class DecodedListCache:
             "hits": self.hits,
             "decodes": self.decodes,
             "decode_s": self.decode_s,
+            "decode_ids": self.decode_ids,
             "evictions": self.evictions,
         }
         if self.policy == "2q":
@@ -618,6 +621,7 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
         distinct: set = set()
         cache = index.decoded_cache
         decodes_before, decode_s_before = cache.decodes, cache.decode_s
+        decode_ids_before = cache.decode_ids
         # winning (cluster, offset) pairs across the whole call, resolved in
         # one pass at the end
         res_q: List[np.ndarray] = []
@@ -868,6 +872,7 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
             select_s=stages.seconds["select"],
             rescore_s=stages.seconds["rescore"],
             decode_s=cache.decode_s - decode_s_before,
+            decode_ids=cache.decode_ids - decode_ids_before,
             upload_bytes=stages.upload_bytes,
             select_calls=select_calls,
             new_shapes=stages.new_shapes,

@@ -16,7 +16,9 @@ instead of as an exception.
 :func:`span` names a stage of the search in the JAX profiler's trace.
 The IVF engine times the same stages into ``SearchStats`` (``arena_s``,
 ``upload_s``, ``select_s``, ``rescore_s``, ``decode_s``), so the per-call
-split is recorded whether or not a profiler runs.
+split is recorded whether or not a profiler runs; ``decode_ids`` counts the
+ids those decodes produced, so ``decode_s / decode_ids`` is the decode's
+cost per id whichever lists the call happened to miss.
 """
 
 from __future__ import annotations
@@ -75,6 +77,7 @@ class SearchStats:
     select_s: float = 0.0      # scan.select: top-k cut incl. device wait
     rescore_s: float = 0.0     # scan.rescore: exact host re-score
     decode_s: float = 0.0      # ids.decode: id-list decodes (in id_resolve_s)
+    decode_ids: int = 0        # ids produced by those decodes
     upload_bytes: int = 0      # bytes handed to the device (retries included)
     select_calls: int = 0      # device-select runs incl. K-doubling retries
     new_shapes: int = 0        # scorer/select signatures first seen this call
@@ -119,6 +122,7 @@ def combine_stats(parts: Sequence[SearchStats], *, wall_s: float,
         out.select_s += s.select_s
         out.rescore_s += s.rescore_s
         out.decode_s += s.decode_s
+        out.decode_ids += s.decode_ids
         out.upload_bytes += s.upload_bytes
         out.select_calls += s.select_calls
         out.new_shapes += s.new_shapes

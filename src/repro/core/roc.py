@@ -26,6 +26,43 @@ bit, with **no initial-bits overhead**: starting from state 0, early
 low-entropy ranks), which is the cleanest resolution of the paper's
 "initial bits issue" for the offline/online settings alike.
 
+Split decode.  Each decode step is ``s <- (s // N) * i + j_i`` with
+``x_i = s % N``, so the loop above touches the whole state, about
+``log2 C(N, n)`` bits, twice per id.  Write ``s = H * N**m + L``
+(``divmod``).  While the ``H`` term is still a multiple of ``N``, which
+holds for ``m`` steps (step ``t`` turns it into ``H * i_1..i_t * N**(m-t)``),
+each ``x_t`` is the current ``L % N`` and ``L`` steps alone:
+``L <- (L // N) * i_t + j_t``.  After the ``m`` steps the state is exactly
+``H * i_1*..*i_m + L``.  ``roc_pop_set`` applies this recursively: split off
+half of the remaining count, decode it from its low part (recursing), put
+the state back together with one multiply, decode the rest the same way.
+Parts of at most ``SPLIT_LEAF_IDS`` ids run the per-id loop on an integer of
+a few dozen machine words; the full-width state is touched ``O(log n)``
+times per list instead of ``2n``.  The ids and the state after are those of
+the per-id loop, bit for bit, so joint streams pop on from the same place.
+
+The leaf size comes from timings of one list decode on an Intel Xeon core
+under CPython 3.12 (universe ``10**6``, median of 9 lists, best of 5 runs,
+in us; "before" is the loop through ``BigANS.pop_uniform``/``push_uniform``,
+"loop" the same steps inline without the split)::
+
+    n       before   loop   leaf 16   leaf 32   leaf 48   leaf 64   leaf 96
+    64          75     42        50        44        42        43        40
+    128        193    105       108        98       100       105       103
+    192        325    190       181       166       162       165       181
+    362        665    381       309       266       264       253       295
+    909       3870   2400      1603      1427      1509      1544      1503
+    2406     20532  12683      6634      6198      6092      6068      6192
+
+Every length takes the split, so there is one path.  A list of
+``SPLIT_LEAF_IDS`` ids or fewer (graph friend lists, small epochs) splits
+once, ``divmod(s, N**n)``: on its own blob that is within noise of the
+loop (16-128 ids: 8.4 against 7.8 us at 16, 11.9 against 11.9 at 32, 110
+against 111 at 128), and on a joint stream, where ``s`` holds every list
+pushed before it, it spares the loop's two full-width operations an id
+(the last of 200 lists of 16 ids: 81 against 314 us; of 127 ids: 2.8
+against 15.2 ms).
+
 Differences from the paper's C++ implementation (documented in DESIGN.md):
 the paper uses a fixed-width streaming ANS where the initial state is filled
 with random bits; we use the exact coder for rate reporting (the paper notes
@@ -36,6 +73,7 @@ vectorized lane coder (``repro.core.gap_ans``) for the TPU-adapted fast path.
 from __future__ import annotations
 
 import bisect
+import math
 from typing import List, Sequence
 
 import numpy as np
@@ -50,6 +88,10 @@ __all__ = [
     "roc_decode_clusters",
     "set_information_bits",
 ]
+
+# The split decode runs the per-id loop on this many ids or fewer at once.
+# Set from the CPU timings in the module docstring.
+SPLIT_LEAF_IDS = 48
 
 
 def roc_push_set(ans: BigANS, ids: Sequence[int], alphabet: int) -> None:
@@ -79,14 +121,40 @@ def roc_push_set(ans: BigANS, ids: Sequence[int], alphabet: int) -> None:
 
 
 def roc_pop_set(ans: BigANS, n: int, alphabet: int) -> np.ndarray:
-    """Pop a set of ``n`` ids; returns them sorted ascending."""
+    """Pop a set of ``n`` ids; returns them sorted ascending.
+
+    Split decode (module docstring): the ids, and ``ans.state`` after, are
+    those of the per-id loop.
+    """
     out: List[int] = []
-    for i in range(1, n + 1):
-        x = ans.pop_uniform(alphabet)
-        j = bisect.bisect_left(out, x)
-        out.insert(j, x)
-        ans.push_uniform(j, i)
+    ans.state = _pop_split(ans.state, out, 1, int(n), int(alphabet))
     return np.asarray(out, dtype=np.int64)
+
+
+def _pop_loop(s: int, out: List[int], a: int, cnt: int, alphabet: int) -> int:
+    """Decode ``cnt`` ids, counters ``i = a .. a+cnt-1``, one per step of
+    ``s``; inserts each into the sorted ``out`` and returns the state after."""
+    insert, rank = out.insert, bisect.bisect_left
+    for i in range(a, a + cnt):
+        s, x = divmod(s, alphabet)           # pop_uniform(alphabet)
+        j = rank(out, x)
+        insert(j, x)
+        s = s * i + j                        # push_uniform(j, i)
+    return s
+
+
+def _pop_split(s: int, out: List[int], a: int, cnt: int, alphabet: int) -> int:
+    """:func:`_pop_loop`'s result, with the per-id steps run on low parts of
+    ``s`` split off by ``divmod`` (see the module docstring)."""
+    if cnt <= SPLIT_LEAF_IDS:
+        high, low = divmod(s, alphabet ** cnt)
+        low = _pop_loop(low, out, a, cnt, alphabet)
+        return high * math.perm(a + cnt - 1, cnt) + low
+    m = cnt // 2
+    high, low = divmod(s, alphabet ** m)
+    low = _pop_split(low, out, a, m, alphabet)
+    s = high * math.perm(a + m - 1, m) + low    # perm = a(a+1)..(a+m-1)
+    return _pop_split(s, out, a + m, cnt - m, alphabet)
 
 
 def roc_encode_clusters(
@@ -125,8 +193,6 @@ def roc_decode_clusters(
 
 def set_information_bits(alphabet: int, n: int) -> float:
     """``log2 C(alphabet, n)`` — the information content of an n-subset."""
-    import math
-
     return (
         math.lgamma(alphabet + 1)
         - math.lgamma(n + 1)

@@ -49,7 +49,8 @@ __all__ = ["AnnService", "AddTicket", "BatchPolicy", "Ticket"]
 
 # SearchStats fields of the batched IVF scan's stages, summed into stats()
 SCAN_STAGE_KEYS = ("arena_s", "upload_s", "select_s", "rescore_s",
-                   "decode_s", "upload_bytes", "select_calls", "new_shapes")
+                   "decode_s", "decode_ids", "upload_bytes", "select_calls",
+                   "new_shapes")
 
 
 @dataclasses.dataclass
@@ -334,6 +335,7 @@ class AnnService:
           ``decode_s`` — cumulative seconds of the IVF scan's stages
           (arena gather, host->device copy, top-k cut incl. the device
           wait, exact re-score, id-list decodes inside ``resolve_s``).
+        * ``decode_ids`` — ids those id-list decodes produced.
         * ``upload_bytes`` / ``select_calls`` / ``new_shapes`` — bytes
           copied to the device, device-select runs (K-doubling retries
           included) and first-seen scorer/select signatures (compiles).

@@ -144,6 +144,25 @@ def test_save_load_file_path(tmp_path, data, centroids, pq):
     np.testing.assert_array_equal(d0, d1)
 
 
+def test_roc_joint_streams_split_decode_roundtrip(data):
+    """Joint ROC streams load to bit-identical search, and re-save to the
+    same bytes, whether a list splits recursively (the built epoch, ~300
+    ids a list) or once, as one leaf (an added epoch of ~30 a list)."""
+    from repro.core.roc import SPLIT_LEAF_IDS
+
+    base, queries = data
+    cents = kmeans(base, 3, iters=4, seed=1)
+    idx = index_factory("IVF3,ids=roc").build(base, seed=1, centroids=cents)
+    idx.add(base[:90] + 0.01)
+    sizes = [ep.sizes for ep in idx.ivf._ids.epochs]
+    assert min(sizes[0]) > 2 * SPLIT_LEAF_IDS
+    assert SPLIT_LEAF_IDS >= max(sizes[1]) and min(sizes[1]) > 0
+    idx2 = _roundtrip(idx, queries, dict(k=10, nprobe=3))
+    blob = save_index(idx)
+    assert save_index(idx2) == blob
+    assert save_index(load_index(blob)) == blob
+
+
 def test_container_rejects_garbage():
     with pytest.raises(ValueError):
         unpack_index(b"NOPE" + b"\x00" * 64)

@@ -1,5 +1,7 @@
 """Tests for the set codecs (ROC, EF, gap-ANS), WT, RRR, REC, Polya, webgraph."""
 
+import bisect
+
 import numpy as np
 import pytest
 
@@ -24,6 +26,7 @@ from repro.core import (
     set_information_bits,
 )
 from repro.core.bitvec import BitVector, pack_lowbits, unpack_lowbits
+from repro.core.roc import SPLIT_LEAF_IDS
 from repro.core.rrr import RRRVector
 from repro.core.webgraph_lite import webgraph_decode, webgraph_encode
 
@@ -94,6 +97,92 @@ def test_roc_property(seed, n):
     ans = BigANS()
     roc_push_set(ans, ids, universe)
     np.testing.assert_array_equal(roc_pop_set(ans, n, universe), np.sort(ids))
+    assert ans.state == 0
+
+
+def _roc_pop_per_id(ans, n, universe):
+    """The per-id ROC decode through ``BigANS`` (oracle of the split decode)."""
+    out = []
+    for i in range(1, n + 1):
+        x = ans.pop_uniform(universe)
+        j = bisect.bisect_left(out, x)
+        out.insert(j, x)
+        ans.push_uniform(j, i)
+    return np.asarray(out, dtype=np.int64)
+
+
+ROC_UNIVERSES = [1, 2, 17, 1000, 10**6, 2**40]
+ROC_SIZES = [0, 1, SPLIT_LEAF_IDS - 1, SPLIT_LEAF_IDS, SPLIT_LEAF_IDS + 1,
+             362, 909, 2406]
+ROC_CASES = [(n, u) for u in ROC_UNIVERSES
+             for n in sorted(set(ROC_SIZES) | {u}) if n <= min(u, 2406)]
+
+
+def _random_ids(rng, n, universe):
+    """``n`` distinct ids below ``universe`` (which may exceed int64 choice)."""
+    if universe <= 10**7:
+        return _random_set(rng, n, universe)
+    return np.unique(rng.integers(0, universe, size=2 * n))[:n]
+
+
+@pytest.mark.parametrize("n,universe", ROC_CASES)
+def test_roc_split_decode_matches_per_id_loop(n, universe):
+    rng = np.random.default_rng(n * 31 + universe % 1009)
+    ids = _random_ids(rng, n, universe)
+    assert ids.size == n
+    ans = BigANS()
+    roc_push_set(ans, ids, universe)
+    oracle = BigANS(ans.state)
+    want = _roc_pop_per_id(oracle, n, universe)
+    got = roc_pop_set(ans, n, universe)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, np.sort(ids))
+    assert ans.state == oracle.state == 0
+
+
+@pytest.mark.parametrize("universe", ROC_UNIVERSES)
+def test_roc_split_decode_joint_stream(universe):
+    """Lists of one joint stream pop back to front: after each list the
+    state is where the per-id loop leaves it, so the next list pops from
+    the same place."""
+    rng = np.random.default_rng(universe % 9973)
+    sizes = [n for n in (909, 1, SPLIT_LEAF_IDS, 0, 2406, 17, 362)
+             if n <= universe]
+    lists = [_random_ids(rng, n, universe) for n in sizes]
+    below = int(rng.integers(1, 2**62))           # stream below the lists
+    ans = BigANS(below)
+    for ids in lists:
+        roc_push_set(ans, ids, universe)
+    oracle = BigANS(ans.state)
+    for n, ids in zip(reversed(sizes), reversed(lists)):
+        want = _roc_pop_per_id(oracle, n, universe)
+        got = roc_pop_set(ans, n, universe)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got, np.sort(ids))
+        assert ans.state == oracle.state
+    assert ans.state == below
+
+
+@pytest.mark.parametrize("n", [
+    1, 16, 32, SPLIT_LEAF_IDS, SPLIT_LEAF_IDS + 1, 2 * SPLIT_LEAF_IDS,
+    2 * SPLIT_LEAF_IDS + 1, 127, 128])
+def test_roc_short_lists_on_joint_stream(n):
+    """Many short lists (graph friend lists, small epochs) on one joint
+    stream: each pops over a state far wider than itself, and must still
+    leave the state exactly where the per-id loop does."""
+    universe = 10**6
+    rng = np.random.default_rng(n)
+    lists = [_random_set(rng, n, universe) for _ in range(40)]
+    ans = BigANS()
+    for ids in lists:
+        roc_push_set(ans, ids, universe)
+    oracle = BigANS(ans.state)
+    for ids in reversed(lists):
+        want = _roc_pop_per_id(oracle, n, universe)
+        got = roc_pop_set(ans, n, universe)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got, np.sort(ids))
+        assert ans.state == oracle.state
     assert ans.state == 0
 
 

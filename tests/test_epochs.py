@@ -227,7 +227,7 @@ def test_cache_lru_stats_shape_unchanged():
     cache = DecodedListCache(max_bytes=1 << 10)
     cache.get("k", lambda: np.zeros(4, np.int64))
     assert set(cache.stats()) == {"entries", "bytes", "hits", "decodes",
-                                  "decode_s", "evictions"}
+                                  "decode_s", "decode_ids", "evictions"}
 
 
 def test_cache_policy_via_factory(data):

@@ -294,11 +294,11 @@ def test_decoded_cache_exact_counts():
     decode_s = st.pop("decode_s")                 # wall time of the 4 misses
     assert decode_s > 0
     assert st == {"entries": 2, "bytes": 160, "hits": 1,
-                  "decodes": 4, "evictions": 2}
+                  "decodes": 4, "decode_ids": 40, "evictions": 2}
     cache.set_budget(100)                         # shrink: evict 2 -> [1]
     assert cache.stats() == {"entries": 1, "bytes": 80, "hits": 1,
                              "decodes": 4, "decode_s": decode_s,
-                             "evictions": 3}
+                             "decode_ids": 40, "evictions": 3}
 
 
 def test_decoded_cache_shared_by_both_paths(data, graphs):

@@ -3,7 +3,8 @@
 The program names its stages in the JAX profiler's trace
 (``repro.ann.stats.span``) and times the same stages into
 ``SearchStats`` (``arena_s``, ``upload_s``, ``select_s``, ``rescore_s``,
-``decode_s``, ``upload_bytes``, ``select_calls``, ``new_shapes``).  These
+``decode_s``, ``decode_ids``, ``upload_bytes``, ``select_calls``,
+``new_shapes``).  These
 tests read a CPU profile for the span tree and check each counter
 against what the shapes of the call say it must be.
 """
@@ -178,6 +179,52 @@ def test_decode_seconds_iff_decodes(data, codec):
     if codec in ("roc", "gap_ans"):
         assert st.decodes == 0 and idx.decoded_cache.decodes > 0
         assert idx.decoded_cache.stats()["decode_s"] > 0
+
+
+@pytest.mark.parametrize("kind", ["flat", "pq"])
+def test_decode_ids_counts_decoded_list_lengths(data, kind):
+    base, queries = data
+    idx = _ivf(base, kind)
+    cache = idx.decoded_cache
+    _, _, cold = idx.search(queries, nprobe=4, topk=10)
+    # a cold cache large enough to keep every list holds what was decoded
+    assert cold.decodes == len(cache) > 0 and cache.evictions == 0
+    decoded = sum(len(v) for v in cache._lists.values())
+    assert cold.decode_ids == decoded == cache.decode_ids
+    assert cache.stats()["decode_ids"] == decoded
+    _, _, warm = idx.search(queries, nprobe=4, topk=10)
+    assert warm.decodes == 0 and warm.decode_ids == 0
+
+
+def test_decode_ids_zero_on_cache_hits():
+    c = scan.DecodedListCache()
+    c.get("a", lambda: np.arange(5))
+    c.get("b", lambda: np.arange(7))
+    assert (c.decodes, c.decode_ids) == (2, 12)
+    c.get("a", lambda: np.arange(100))
+    c.get("b", lambda: np.arange(100))
+    assert (c.hits, c.decodes, c.decode_ids) == (2, 2, 12)
+
+
+def test_combine_stats_sums_decode_ids():
+    parts = [SearchStats(wall_s=1.0, ndis=1, id_resolve_s=0.1,
+                         decode_ids=n) for n in (3, 0, 909)]
+    assert combine_stats(parts, wall_s=1.0).decode_ids == 912
+
+
+def test_service_decode_ids_sum_and_reset(data):
+    base, queries = data
+    idx = _ivf(base, "flat")
+    svc = AnnService(idx, topk=10, nprobe=4, policy=BatchPolicy(max_batch=8))
+    want = 0
+    for q in np.array_split(queries, 3):
+        svc.submit(q)
+        svc.flush()
+        want += svc.last_stats.decode_ids
+    assert svc.stats()["decode_ids"] == want
+    assert want == idx.decoded_cache.decode_ids > 0
+    svc.reset_stats()
+    assert svc.stats()["decode_ids"] == 0
 
 
 @pytest.mark.parametrize("select", ["host", "device"])
