@@ -415,6 +415,15 @@ def _device_selector():
     ``k`` smallest ``(value, column)`` pairs — so the ``(qb, C_pad)``
     distance block never crosses the device boundary; only ``(qb, k)``
     values, candidate columns and arena positions return to the host.
+
+    The map is a dense compare over the P probes, with no search loop and
+    no per-column gather: probe ``j`` owns columns ``[lo_j, cum_j)`` of
+    its row (the host's ``_spans_concat`` order; a zero-size probe owns
+    none), and a column's arena position is the column plus its owner's
+    shift ``start_of[probes_j] - lo_j``.  Padding columns, past the row's
+    total, own nothing: they are masked to ``+inf`` and the host drops
+    their positions.  The only gather over ``(qb, C_pad)`` is the
+    distance gather from ``dmat``.
     """
     jax, jnp = _jax(), _jax().numpy
 
@@ -425,19 +434,13 @@ def _device_selector():
 
         pp = size_of[probes]                       # (qb_pad, P)
         cum = jnp.cumsum(pp, axis=1)
+        lo = cum - pp                              # probe j's first column
+        delta = start_of[probes] - lo              # arena pos - column
         col = jnp.arange(c_pad, dtype=jnp.int32)
-        # probe owning each candidate column: count of probe-end offsets
-        # <= col (side="right" skips zero-size probes, matching the host
-        # _spans_concat concatenation exactly)
-        pidx = jax.vmap(lambda c: jnp.searchsorted(c, col, side="right"))(cum)
+        own = (lo[:, :, None] <= col) & (col < cum[:, :, None])
+        pos = col + jnp.sum(jnp.where(own, delta[:, :, None], 0), axis=1)
         total = cum[:, -1][:, None]
         valid = col[None, :] < total
-        pc = jnp.minimum(pidx, pp.shape[1] - 1)
-        prev = jnp.where(
-            pidx > 0,
-            jnp.take_along_axis(cum, jnp.maximum(pidx, 1) - 1, axis=1), 0)
-        cl = jnp.take_along_axis(probes, pc, axis=1)
-        pos = start_of[cl] + (col[None, :] - prev)
         pos = jnp.clip(pos, 0, dmat.shape[1] - 1).astype(jnp.int32)
         d = jnp.where(valid, jnp.take_along_axis(dmat, pos, axis=1),
                       jnp.inf)

@@ -13,6 +13,7 @@ this file loads the TPU compiler library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +113,28 @@ def test_device_selector_compiles(one_chip):
                              _spec(one_chip, (1024,), jnp.int32),
                              c_pad=ARENA, k=32, engine="pallas",
                              interpret=False))
+
+
+def test_device_selector_map_has_no_loop(one_chip):
+    """The candidate->arena map compiles to dense vector work: no ``while``
+    (a search loop) and one gather fusion over the ``(qb, c_pad)`` block,
+    the f32 distance gather from the scored block."""
+    from repro.ann.scan import _device_selector
+
+    c_pad = 32768
+    text = _device_selector().lower(
+        _spec(one_chip, (QB, 1 << 19)),
+        _spec(one_chip, (QB, 16), jnp.int32),
+        _spec(one_chip, (1024,), jnp.int32),
+        _spec(one_chip, (1024,), jnp.int32),
+        c_pad=c_pad, k=32, engine="pallas", interpret=False,
+    ).compile().as_text()
+    assert "while(" not in text
+    gather = re.compile(rf"^\s*%\S+ = (\w+)\[{QB * c_pad}\]\S* fusion\("
+                        r'.*kind=kCustom.*op_name="[^"]*gather"')
+    dtypes = [m.group(1) for line in text.splitlines()
+              if (m := gather.match(line))]
+    assert dtypes == ["f32"]
 
 
 def test_flat_select_runner_compiles(one_chip):
