@@ -17,8 +17,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", nargs="*", default=None,
-                    help="subset: table1 table2 table3 table4 fig3 moe codec "
-                         "roofline graph spec shard ingest select")
+                    help="subset: table1 table2 table3 table4 fig3 codec "
+                         "graph spec shard ingest select")
     ap.add_argument("--spec", action="append", default=None,
                     help="factory spec string for the 'spec' suite "
                          "(repeatable); implies --only spec when --only is "
@@ -29,9 +29,9 @@ def main() -> None:
 
     enable_compile_cache()
     from . import (codec_speed, fig3_code_compression, graph_bench,
-                   ingest_bench, moe_routing, roofline, select_bench,
-                   shard_bench, spec_bench, table1_bpe, table2_search_time,
-                   table3_offline_graph, table4_large_scale)
+                   ingest_bench, select_bench, shard_bench, spec_bench,
+                   table1_bpe, table2_search_time, table3_offline_graph,
+                   table4_large_scale)
 
     suites = {
         "table1": table1_bpe.main,
@@ -39,9 +39,7 @@ def main() -> None:
         "table3": table3_offline_graph.main,
         "table4": table4_large_scale.main,
         "fig3": fig3_code_compression.main,
-        "moe": moe_routing.main,
         "codec": codec_speed.main,
-        "roofline": roofline.main,
         "graph": graph_bench.main,
         "shard": shard_bench.main,
         "ingest": ingest_bench.main,

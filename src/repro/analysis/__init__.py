@@ -12,7 +12,7 @@ RPA002      lock discipline in executor-backed services
 RPA003      serialization determinism in container/blob writers
 RPA004      overflow/width contracts on wide bit-pack shifts
 RPA005      jit/Pallas purity in traced functions
-RPA006      broad-except hygiene (allowlist + must record the failure)
+RPA006      broad-except hygiene (name the concrete failure types)
 ==========  ===============================================================
 
 Suppress one line with ``# repro: ignore[RPA001]`` (or a bare

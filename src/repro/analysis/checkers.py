@@ -27,10 +27,8 @@ heads and after-the-fact parity tests:
   stay traceable: no host prints, ``.item()``/``tolist()``, Python
   scalar coercions, host-``np`` calls (silent constant-folding),
   wall-clock reads, or Python-side mutation.
-* **RPA006 broad-except hygiene** — ``except Exception`` (or bare
-  ``except``) only in the failure-harvesting allowlist, and such
-  handlers must record the failure; everywhere else the concrete
-  failure types must be named.
+* **RPA006 broad-except hygiene** — no ``except Exception`` (or bare
+  ``except``): the concrete failure types must be named.
 * **RPA007 spec-grammar/docs drift** — the option keys
   ``repro.api.spec`` actually parses (``KNOWN_OPTION_KEYS``) and the
   keys documented in the ``spec-grammar`` block of
@@ -673,33 +671,15 @@ class BroadExceptChecker(Checker):
     rule = "RPA006"
     title = "broad-except hygiene"
 
-    #: failure-harvesting modules where `except Exception` is the contract
-    ALLOWLIST = ("repro/launch/dryrun.py",)
-    #: an allowlisted handler must reference one of these (record the fault)
-    RECORD_MARKERS = ("error", "stats", "fault", "partial", "record", "log")
-
     def check(self, ctx: ModuleContext) -> List[Finding]:
-        allowlisted = ctx.path in self.ALLOWLIST
-        out: List[Finding] = []
-        for node in ast.walk(ctx.tree):
-            if not isinstance(node, ast.ExceptHandler):
-                continue
-            if not self._is_broad(node.type):
-                continue
-            if allowlisted:
-                if not self._records(node):
-                    out.append(self.finding(
-                        ctx, node,
-                        "allowlisted broad except must record the failure "
-                        "(stats/error/fault log), not swallow it"))
-            else:
-                out.append(self.finding(
+        return [self.finding(
                     ctx, node,
-                    "broad `except Exception` outside the fault-handling "
-                    "allowlist; catch the concrete failure types (e.g. "
-                    "ShardTimeout/ShardDead/TimeoutError) and record into "
-                    "stats"))
-        return out
+                    "broad `except Exception`; catch the concrete failure "
+                    "types (e.g. ShardTimeout/ShardDead/TimeoutError) and "
+                    "record into stats")
+                for node in ast.walk(ctx.tree)
+                if isinstance(node, ast.ExceptHandler)
+                and self._is_broad(node.type)]
 
     @staticmethod
     def _is_broad(type_node: Optional[ast.AST]) -> bool:
@@ -711,21 +691,6 @@ class BroadExceptChecker(Checker):
                    and n.split(".")[-1] in ("Exception", "BaseException")
                    for n in names)
 
-    def _records(self, handler: ast.ExceptHandler) -> bool:
-        for node in ast.walk(handler):
-            words: List[str] = []
-            if isinstance(node, ast.Name):
-                words.append(node.id)
-            elif isinstance(node, ast.Attribute):
-                words.append(node.attr)
-            elif isinstance(node, ast.Constant) and isinstance(node.value,
-                                                               str):
-                words.append(node.value)
-            for w in words:
-                lw = w.lower()
-                if any(m in lw for m in self.RECORD_MARKERS):
-                    return True
-        return False
 
 
 # ---------------------------------------------------------------------------

@@ -120,6 +120,58 @@ def rescore_eps(d: int, bound: float, qn: float, factor: float = 16.0) -> float:
     return factor * d * float(np.finfo(np.float32).eps) * scale
 
 
+def _shortlist(select, nvalid: np.ndarray, topk: int, d: int,
+               qn: np.ndarray, width: int):
+    """The re-score shortlist of one query block: the one band decision.
+
+    Kernel distances only have to get the top-k *set* right.  The
+    expanded ``qn - 2qc + cn`` form cancels catastrophically for
+    near-duplicate vectors, so row ``i`` keeps every candidate within
+    ``rescore_eps`` of its ``take``-th smallest kernel distance,
+    ``take = min(topk + RESCORE_SLACK, nvalid[i])``, and the exact
+    re-score sees every potential top-k member.
+
+    ``select(K)`` returns a tuple whose first two entries are the block's
+    kernel distances, ascending along each row, and their candidate
+    columns, both ``(qb, K')``; further entries ride along (the device
+    select's arena positions).  ``nvalid[i]`` counts row ``i``'s real
+    candidates (columns at or past it are padding) and ``qn[i]`` is its
+    query norm (0 for PQ).  While a row with more than ``K'`` candidates
+    still has its ``K'``-th value inside its band, ``K`` doubles, up to
+    ``width``, and ``select`` runs again; a ``select`` that returns
+    whole sorted rows never runs twice.
+
+    Returns ``(out, thr, cut)``: the last ``select`` output, the
+    per-row thresholds (``-inf`` for an empty row), and ``cut(i)``,
+    the indices into row ``i`` of ``out`` inside its band, padding
+    dropped (a real ``+inf`` keeps its column), in candidate order.
+    """
+    take = np.minimum(topk + RESCORE_SLACK, nvalid)
+    K = min(_bucket(int(take.max(initial=0)), floor=16), width)
+    while True:
+        out = select(K)
+        vals = out[0]
+        k_sel = vals.shape[1]
+        thr = np.full(len(nvalid), -np.inf)
+        retry = False
+        for i in np.flatnonzero(take):
+            bound = float(vals[i, take[i] - 1])
+            thr[i] = bound + rescore_eps(d, bound, float(qn[i]))
+            if nvalid[i] > k_sel and vals[i, k_sel - 1] <= thr[i]:
+                retry = True            # the band may reach past the cut
+        if not retry or K >= width:
+            break
+        K = min(2 * K, width)
+
+    def cut(i: int) -> np.ndarray:
+        cols = out[1][i]
+        cnt = int(np.searchsorted(vals[i], thr[i], side="right"))
+        kept = np.flatnonzero(cols[:cnt] < nvalid[i])
+        return kept[np.argsort(cols[kept])]
+
+    return out, thr, cut
+
+
 # ---------------------------------------------------------------------------
 # decoded-list LRU cache
 # ---------------------------------------------------------------------------
@@ -756,16 +808,13 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
                     res_key.append(pack_merge_keys(
                         rank_of[i, uniq[span_of]], p - arena_start[span_of]))
 
+            qn = np.zeros(qb) if use_pq else qn_host
             if _resolve_select(select, c_pad, select_min):
                 # --- device-side segmented top-k ---------------------------
                 # the (qb, C_pad) block stays on device: a jitted gather +
                 # seg_topk returns (qb, K) shortlist values / candidate
-                # columns / arena positions, the host recomputes the SAME
-                # short-list threshold the host path uses (bound of the
-                # take-th smallest kernel value + rescore_eps, in float64
-                # over identical f32 values), and K doubles while any row's
-                # shortlist might extend past it — so the cut set matches
-                # the host path exactly.
+                # columns / arena positions, and K doubles while any row's
+                # band might extend past it (``_shortlist``)
                 n_dev_select += 1
                 with stages.stage("select"):
                     runner = _device_selector()
@@ -775,56 +824,28 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
                     probes_pad[:qb] = blk_probes
                     start32 = np.maximum(start_of, 0).astype(np.int32)
                     size32 = size_of.astype(np.int32)
-                    K = min(_bucket(min(topk + RESCORE_SLACK, c_pad),
-                                    floor=16), c_pad_b)
-                    while True:
+
+                    def select_k(K):
+                        nonlocal select_calls, host_block_bytes
                         select_calls += 1
-                        vals_d, cols_d, pos_d = stages.dispatch(
+                        out = stages.dispatch(
                             (f"select:{index.nlist}", qb_pad, u_pad,
                              blk_probes.shape[1], c_pad_b, K, engine),
                             runner, dmat, stages.put(probes_pad),
                             stages.put(start32), stages.put(size32),
                             c_pad=c_pad_b, k=K, engine=engine,
                             interpret=interpret)
-                        vals = np.asarray(vals_d)
-                        sel_cols = np.asarray(cols_d)
-                        sel_pos = np.asarray(pos_d)
-                        host_block_bytes += (vals.nbytes + sel_cols.nbytes
-                                             + sel_pos.nbytes)
-                        vals = vals[:qb]
-                        thr = np.full(qb, -np.inf)
-                        retry = False
-                        for i in range(qb):
-                            nvalid = int(cand_lens[i])
-                            if nvalid == 0:
-                                continue
-                            take = min(topk + RESCORE_SLACK, nvalid)
-                            bound = float(vals[i, take - 1])
-                            eps = rescore_eps(
-                                index.d, bound,
-                                0.0 if use_pq else float(qn_host[i]))
-                            thr[i] = bound + eps
-                            if nvalid > K and vals[i, K - 1] <= thr[i]:
-                                retry = True  # band may extend past the cut
-                        if not retry or K >= c_pad_b:
-                            break
-                        K = min(2 * K, c_pad_b)
+                        out = [np.asarray(a) for a in out]
+                        host_block_bytes += sum(a.nbytes for a in out)
+                        return [a[:qb] for a in out]
+
+                    (_, _, sel_pos), _, cut = _shortlist(
+                        select_k, cand_lens, topk, index.d, qn, c_pad_b)
                 with stages.stage("rescore"):
                     for i in range(qb):
-                        qi = q0 + i
-                        nvalid = int(cand_lens[i])
-                        if nvalid == 0:
-                            continue
-                        # vals are ascending: count the entries inside the
-                        # band, drop padding columns (>= nvalid; real +inf
-                        # hits keep their column < nvalid), restore oracle
-                        # concat order
-                        cnt = int(np.searchsorted(vals[i], thr[i],
-                                                  side="right"))
-                        cc, pp_sel = sel_cols[i, :cnt], sel_pos[i, :cnt]
-                        real = cc < nvalid
-                        cc, pp_sel = cc[real], pp_sel[real]
-                        finish(i, qi, pp_sel[np.argsort(cc)].astype(np.int64))
+                        if cand_lens[i]:
+                            finish(i, q0 + i,
+                                   sel_pos[i, cut(i)].astype(np.int64))
             else:
                 # --- host-side stable top-k over the pulled padded block ---
                 with stages.stage("select"):
@@ -838,34 +859,17 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
                         np.inf,
                     ).astype(np.float32)
                     order = np.argsort(d_blk, axis=1, kind="stable")
+                    sorted_blk = (np.take_along_axis(d_blk, order, axis=1),
+                                  order)
                 with stages.stage("rescore"):
+                    # whole sorted rows: one pass, no retry.  Candidate
+                    # columns are the oracle's concat positions, so the
+                    # cut's candidate order is the oracle's tie order.
+                    _, _, cut = _shortlist(lambda K: sorted_blk, cand_lens,
+                                           topk, index.d, qn, c_pad)
                     for i in range(qb):
-                        qi = q0 + i
-                        nvalid = int(cand_lens[i])
-                        take = min(topk + RESCORE_SLACK, nvalid)
-                        if take == 0:
-                            continue
-                        # kernel distances only have to get the top-k
-                        # *set* right.  The expanded qn-2qc+cn form cancels
-                        # catastrophically for near-duplicate vectors, so
-                        # candidates near the shortlist boundary may be
-                        # mis-ranked by up to the cancellation error —
-                        # extend the shortlist through that error band so
-                        # the exact re-score below sees every potential
-                        # top-k member.
-                        row = d_blk[i]
-                        bound = float(row[order[i, take - 1]])
-                        eps = rescore_eps(
-                            index.d, bound,
-                            0.0 if use_pq else float(qn_host[i]))
-                        while (take < nvalid
-                               and row[order[i, take]] <= bound + eps):
-                            take += 1
-                        # candidate *row positions* are the oracle's concat
-                        # positions: sorting them restores the oracle's
-                        # stable tie order.
-                        sel = np.sort(order[i, :take])
-                        finish(i, qi, cand_pos[i, sel])
+                        if cand_lens[i]:
+                            finish(i, q0 + i, cand_pos[i, order[i, cut(i)]])
             with stages.stage("arena"):
                 # release the block's arena on the host and the device
                 # inside the stage, not at the next fill or the return
@@ -963,7 +967,6 @@ def batched_flat_search(vecs: np.ndarray, queries: np.ndarray,
     queries = np.asarray(queries, np.float32)
     nq, d = queries.shape
     n = vecs.shape[0]
-    topk_eff = min(topk, n)
     all_ids = np.zeros((nq, topk), np.int64)
     all_d = np.full((nq, topk), np.inf, np.float32)
     runner = _flat_select_runner()
@@ -984,35 +987,22 @@ def batched_flat_search(vecs: np.ndarray, queries: np.ndarray,
         qblk[:qb] = queries[q0:q1]
         qblk_dev = jnp.asarray(qblk)
         qn_host = np.einsum("qd,qd->q", qblk[:qb], qblk[:qb])
-        K = min(_bucket(min(topk_eff + RESCORE_SLACK, n), floor=16), n_pad)
-        while True:
-            vals_d, cols_d = runner(qblk_dev, base_dev, k=K, engine=engine,
-                                    interpret=interpret, nvalid=n)
-            vals = np.asarray(vals_d)
-            cols = np.asarray(cols_d)
-            host_block_bytes += vals.nbytes + cols.nbytes
-            vals = vals[:qb]
-            thr = np.full(qb, -np.inf)
-            retry = False
-            for i in range(qb):
-                take = min(topk_eff + RESCORE_SLACK, n)
-                if take == 0:
-                    continue
-                bound = float(vals[i, take - 1])
-                eps = rescore_eps(d, bound, float(qn_host[i]))
-                thr[i] = bound + eps
-                if n > K and vals[i, K - 1] <= thr[i]:
-                    retry = True        # band may extend past the K cut
-            if not retry or K >= n_pad:
-                break
-            K = min(2 * K, n_pad)
+
+        def select_k(K):
+            nonlocal host_block_bytes
+            out = [np.asarray(a) for a in runner(
+                qblk_dev, base_dev, k=K, engine=engine, interpret=interpret,
+                nvalid=n)]
+            host_block_bytes += sum(a.nbytes for a in out)
+            return [a[:qb] for a in out]
+
+        (_, cols), _, cut = _shortlist(select_k, np.full(qb, n), topk, d,
+                                       qn_host, n_pad)
         for i in range(qb):
             qi = q0 + i
             if n == 0:
                 continue
-            cnt = int(np.searchsorted(vals[i], thr[i], side="right"))
-            rows = cols[i, :cnt]
-            rows = np.sort(rows[rows < n]).astype(np.int64)
+            rows = cols[i, cut(i)].astype(np.int64)
             d_exact = score_rows_flat(vecs[rows], queries[qi])
             best = select_topk(d_exact, topk)
             n_found = best.shape[0]

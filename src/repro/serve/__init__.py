@@ -1,15 +1,11 @@
-"""Serving surface: prefill/decode step builders, cache utilities, and the
-ANN micro-batching service.
+"""Serving surface: the micro-batching ANN service.
 
-The LM implementations live next to their training counterparts
-(repro.train.step) and the model cache constructors; the ANN service wraps
-the batched compressed-IVF scan (repro.ann.scan).  This package is the
-stable import point a serving deployment uses.
+``AnnService`` queues search and ingest requests, flushes them as batches
+through any ``repro.api`` index (the batched scan engines of
+``repro.ann``), and keeps the latency and decode-cache accounting.  This
+package is the stable import point a serving deployment uses.
 """
 
-from ..models.attention import KVCache, init_cache
-from ..train.step import make_prefill_step, make_serve_step
 from .ann_service import AddTicket, AnnService, BatchPolicy, Ticket
 
-__all__ = ["KVCache", "init_cache", "make_prefill_step", "make_serve_step",
-           "AnnService", "AddTicket", "BatchPolicy", "Ticket"]
+__all__ = ["AnnService", "AddTicket", "BatchPolicy", "Ticket"]

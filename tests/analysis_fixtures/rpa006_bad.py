@@ -2,7 +2,7 @@
 def risky(work):
     try:
         work()
-    except Exception:  # FIRE (broad, outside the allowlist)
+    except Exception:  # FIRE (broad)
         pass
     try:
         work()

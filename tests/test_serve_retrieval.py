@@ -1,70 +1,45 @@
-"""Serving loop + retrieval feature integration tests."""
+"""Retrieval through the ``repro.api`` factory, end to end."""
 
 import numpy as np
-import pytest
 
-from repro.configs import ARCH_IDS
+from repro.api import index_factory, load_index, save_index
 from repro.data.synthetic import make_dataset
-from repro.launch.serve import main as serve_main
-from repro.retrieval.index import RetrievalIndex
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("arch", ["gemma3-1b", "zamba2-2.7b", "xlstm-1.3b",
-                                  "olmoe-1b-7b", "whisper-medium", "qwen2-vl-7b"])
-def test_serve_driver_generates(arch):
-    out = serve_main(["--arch", arch, "--reduced", "--batch", "2",
-                      "--prompt-len", "4", "--gen", "6"])
-    assert out.shape == (6, 2)
-    assert np.isfinite(out).all()
-
-
-@pytest.mark.slow
-def test_serve_decode_is_deterministic():
-    a = serve_main(["--arch", "gemma3-1b", "--reduced", "--batch", "2",
-                    "--prompt-len", "4", "--gen", "8"])
-    b = serve_main(["--arch", "gemma3-1b", "--reduced", "--batch", "2",
-                    "--prompt-len", "4", "--gen", "8"])
-    np.testing.assert_array_equal(a, b)
 
 
 def test_retrieval_index_end_to_end():
-    base, queries = make_dataset("deep-like", 20_000, 64, seed=0)
-    ri = RetrievalIndex(nlist=64, id_codec="roc").build(base)
-    stats = ri.stats()
-    assert stats["bits_per_id"] < stats["compact_bits"] - 2
-    ids, _, _ = ri.search(base[:32], nprobe=8, topk=5)
+    base, _ = make_dataset("deep-like", 20_000, 64, seed=0)
+    idx = index_factory("IVF64,ids=roc").build(base)
+    compact_bits = float(np.ceil(np.log2(idx.n)))
+    assert idx.ivf.bits_per_id() < compact_bits - 2
+    _, ids, _ = idx.search(base[:32], k=5, nprobe=8)
     # self-retrieval: the query vector itself must come back first
     assert np.mean(ids[:, 0] == np.arange(32)) > 0.9
 
 
 def test_retrieval_index_with_pq_codes():
     base, _ = make_dataset("sift-like", 20_000, 16, seed=0)
-    ri = RetrievalIndex(nlist=32, id_codec="gap_ans", pq_m=8,
-                        code_codec="polya").build(base)
-    s = ri.stats()
-    assert s["code_bits_per_element"] <= 8.2
-    ids, _, _ = ri.search(base[:8], nprobe=8, topk=3)
+    idx = index_factory("IVF32,PQ8x8,ids=gap_ans,codes=polya").build(base)
+    assert idx.ivf.code_bits_per_element() <= 8.2
+    _, ids, _ = idx.search(base[:8], k=3, nprobe=8)
     assert ids.shape == (8, 3)
 
 
 def test_retrieval_index_is_spec_thin():
-    """RetrievalIndex is now a thin composition over the api factory:
-    any spec serves, and the whole thing persists as one RIDX artifact."""
+    """Any spec serves through the factory, and the whole index persists
+    as one RIDX artifact."""
     base, queries = make_dataset("deep-like", 3_000, 16, seed=0)
-    ri = RetrievalIndex(spec="IVF32,PQ8x8,ids=roc,codes=polya").build(base)
-    assert ri.index.spec == "IVF32,PQ8x8,ids=roc,codes=polya"
-    ids0, d0, _ = ri.search(queries, topk=5, nprobe=8)
-    blob = ri.save()
-    ri2 = RetrievalIndex.load(blob)
-    ids1, d1, _ = ri2.search(queries, topk=5, nprobe=8)
+    idx = index_factory("IVF32,PQ8x8,ids=roc,codes=polya").build(base)
+    assert idx.spec == "IVF32,PQ8x8,ids=roc,codes=polya"
+    d0, ids0, _ = idx.search(queries, k=5, nprobe=8)
+    idx2 = load_index(save_index(idx))
+    d1, ids1, _ = idx2.search(queries, k=5, nprobe=8)
     np.testing.assert_array_equal(ids0, ids1)
     np.testing.assert_array_equal(d0, d1)
     # graph spec through the same front door
-    rg = RetrievalIndex(spec="NSG8,ids=ef").build(base[:400])
-    gids, _, gst = rg.search(queries, topk=5, ef=16)
+    g = index_factory("NSG8,ids=ef").build(base[:400])
+    _, gids, gst = g.search(queries, k=5, ef=16)
     assert gids.shape == (16, 5) and gst.visited > 0
-    assert rg.stats()["bits_per_edge"] > 0
+    assert g.graph.bits_per_edge() > 0
 
 
 def test_ivf_container_roundtrip():
@@ -96,8 +71,6 @@ def test_public_import_surface():
     from repro.api import (Index, index_factory, load_index,  # noqa: F401
                            parse_spec, save_index)
     from repro.core import CODEC_NAMES, get_codec
-    from repro.distributed.sp import sp_decode_attention  # noqa: F401
-    from repro.serve import make_prefill_step, make_serve_step  # noqa: F401
 
     assert set(CODEC_NAMES) >= {"unc64", "compact", "ef", "roc", "gap_ans"}
     for name in CODEC_NAMES:
