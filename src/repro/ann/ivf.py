@@ -1,7 +1,9 @@
 """IVF index with pluggable id/code compression — the paper's main testbed.
 
 Build: k-means coarse quantizer (K clusters), vectors stored per cluster
-(flat f32 or PQ codes, PQ codes optionally Pólya-coded per Eq. 6-7), ids
+(flat f32 or PQ codes, PQ codes optionally Pólya-coded per Eq. 6-7; the
+grouped rows are one :class:`repro.ann.payload.PayloadStore`, on the
+device from the first batched search on), ids
 stored through any ``repro.core.codecs`` codec (paper's online setting:
 one stream per cluster) or jointly through a wavelet tree (full random
 access, §4.1).
@@ -37,6 +39,7 @@ import numpy as np
 from ..core.epoch import EpochStore
 from ..core.polya import PolyaCodec
 from .kmeans import assign, kmeans
+from .payload import PayloadStore
 from .pq import ProductQuantizer
 from .scan import (CacheOwnerMixin, batched_search, coarse_probes,
                    resolve_ids_batch, score_rows_flat, select_topk)
@@ -75,23 +78,16 @@ class IVFIndex(CacheOwnerMixin):
         if self.pq is not None:
             if self.pq.codebooks is None:
                 self.pq.train(x)
-            codes = self.pq.encode(x)
-            self.codes = codes[order]          # grouped by cluster
-            self.vecs = None
+            self.payload = PayloadStore(self.pq.encode(x)[order])
         else:
-            self.codes = None
-            self.vecs = x[order].astype(np.float32)
+            self.payload = PayloadStore(x[order].astype(np.float32))
         # --- id compression: one epoch over [0, n) ------------------------------
         self._ids = EpochStore(self.nlist, self.id_codec)
         self._ids.append(self._lists, 0, self.n)
         # --- optional code compression ------------------------------------------
-        if self.code_codec == "polya" and self.codes is not None:
+        if self.code_codec == "polya" and self.pq is not None:
             self._polya = PolyaCodec()
-            per_cluster = [
-                self.codes[self.offsets[k]: self.offsets[k + 1]]
-                for k in range(self.nlist)
-            ]
-            self._code_blobs = [self._polya.encode(per_cluster)]
+            self._code_blobs = [self._polya.encode(self._per_cluster())]
         else:
             self._code_blobs = None
         self._decoded_cache = self._new_cache()
@@ -151,29 +147,24 @@ class IVFIndex(CacheOwnerMixin):
         # batched scanner's offsets/sizes/arena layout unchanged)
         rel_lists: List[np.ndarray] = []
         epoch_codes: List[np.ndarray] = []
-        vec_parts: List[np.ndarray] = []
+        parts: List[np.ndarray] = []
+        held = self.payload.host()
+        new_rows = new_codes if self.pq is not None else x_new
         for k in range(self.nlist):
             sel = assign_new == k
             rel_lists.append(new_ids[sel] - base)
             self._lists[k] = np.concatenate([self._lists[k], new_ids[sel]])
-            lo, hi = self.offsets[k], self.offsets[k + 1]
+            parts.append(held[self.offsets[k]: self.offsets[k + 1]])
+            if sel.any():
+                parts.append(new_rows[sel])
             if self.pq is not None:
-                vec_parts.append(self.codes[lo:hi])
-                if sel.any():
-                    vec_parts.append(new_codes[sel])
                 epoch_codes.append(
                     new_codes[sel] if new_codes is not None
                     else np.zeros((0, self.pq.m), np.uint8))
-            else:
-                vec_parts.append(self.vecs[lo:hi])
-                if sel.any():
-                    vec_parts.append(x_new[sel])
         self.sizes = self.sizes + np.bincount(assign_new, minlength=self.nlist)
         self.offsets = np.concatenate([[0], np.cumsum(self.sizes)]).astype(np.int64)
-        if self.pq is not None:
-            self.codes = np.concatenate(vec_parts, axis=0)
-        else:
-            self.vecs = np.concatenate(vec_parts, axis=0)
+        # the regrouped rows go back to the host; the next search places them
+        self.payload = PayloadStore(np.concatenate(parts, axis=0))
         # cluster_of stays universe-sized; only locally-held rows are known
         # (a shard leaves its unowned slots at 0, same as the RIDX loader)
         ext = np.zeros(count, np.int64)
@@ -204,9 +195,7 @@ class IVFIndex(CacheOwnerMixin):
         """
         self._ids.compact(self._lists, self.n)
         if self._code_blobs is not None:
-            per_cluster = [self.codes[self.offsets[k]: self.offsets[k + 1]]
-                           for k in range(self.nlist)]
-            self._code_blobs = [self._polya.encode(per_cluster)]
+            self._code_blobs = [self._polya.encode(self._per_cluster())]
         # epoch indices restarted at 0: stale (epoch, cluster) keys would alias
         self.decoded_cache.clear()
         return self
@@ -234,9 +223,13 @@ class IVFIndex(CacheOwnerMixin):
             return None
         if len(self._code_blobs) == 1:
             return self._code_blobs[0]
-        per_cluster = [self.codes[self.offsets[k]: self.offsets[k + 1]]
-                       for k in range(self.nlist)]
-        return self._polya.encode(per_cluster)
+        return self._polya.encode(self._per_cluster())
+
+    def _per_cluster(self) -> List[np.ndarray]:
+        """The payload's rows split by cluster (one pull of the store)."""
+        held = self.payload.host()
+        return [held[self.offsets[k]: self.offsets[k + 1]]
+                for k in range(self.nlist)]
 
     # -- id resolution (the §4.1 trick) --------------------------------------------
     def resolve_ids(self, clusters: np.ndarray, offsets: np.ndarray) -> np.ndarray:
@@ -292,6 +285,7 @@ class IVFIndex(CacheOwnerMixin):
         res_s = 0.0
         distinct: set = set()
         decodes0 = self.decoded_cache.decodes
+        held = self.payload.host()
         for qi in range(nq):
             cand_d: List[np.ndarray] = []
             cand_k: List[np.ndarray] = []
@@ -302,9 +296,9 @@ class IVFIndex(CacheOwnerMixin):
                     continue
                 distinct.add(int(k))
                 if self.pq is not None:
-                    d = ProductQuantizer.adc_score(self.codes[lo:hi], tables[qi])
+                    d = ProductQuantizer.adc_score(held[lo:hi], tables[qi])
                 else:
-                    d = score_rows_flat(self.vecs[lo:hi], queries[qi])
+                    d = score_rows_flat(held[lo:hi], queries[qi])
                 ndis += hi - lo
                 cand_d.append(d)
                 cand_k.append(np.full(hi - lo, k, np.int32))

@@ -11,7 +11,10 @@ Faiss and Zoom get their throughput from:
    bit-identical).
 2. **Cluster dedup + arena gather**: the union of probed clusters across a
    query block is gathered once into a contiguous "arena" of vectors / PQ
-   codes (each cluster appears once however many queries probe it).
+   codes (each cluster appears once however many queries probe it).  The
+   gather runs on the device, from the index's payload, which the first
+   search places in HBM (:class:`repro.ann.payload.PayloadStore`): only
+   the arena's int32 row numbers cross to the device per block.
 3. **Blocked scoring** of the query block against the arena through the
    Pallas kernels (``l2_dist`` / ``pq_adc``; interpret-mode on CPU) or a
    pure-XLA fallback — both jitted once per bucketed shape.
@@ -19,7 +22,8 @@ Faiss and Zoom get their throughput from:
    (topk + ``RESCORE_SLACK``)-th best kernel distance is re-scored with
    the *same numpy scalar path the oracle uses*, so returned ids **and
    distances** are bit-identical to ``search_ref`` (kernel float error
-   only reorders the short-list, never the result).  The short-list is
+   only reorders the short-list, never the result); the short-lists'
+   rows come back from the device in one copy a block.  The short-list is
    cut either host-side (a stable masked argsort over the pulled
    ``(qb, C_pad)`` block) or **device-side** (``select="device"``): a
    jitted candidate gather + segmented top-k (``repro.kernels.seg_topk``)
@@ -69,6 +73,11 @@ __all__ = [
 # extra short-list entries re-scored exactly: kernel scoring only has to get
 # the top-k *set* right up to this slack, never the exact float ordering.
 RESCORE_SLACK = 8
+# shortlist columns whose payload rows ride with each device-select run:
+# a query's band (``_shortlist``) rarely holds more than topk +
+# RESCORE_SLACK candidates; a block where one holds more pulls its rows
+# after the cut instead
+SHORTLIST_ROWS = 32
 DEFAULT_QUERY_BLOCK = 64
 # select="auto" tile gate (the kernel_min analogue): on CPU the host numpy
 # select competes with an interpreted/jitted device select plus its dispatch,
@@ -134,7 +143,7 @@ def _shortlist(select, nvalid: np.ndarray, topk: int, d: int,
     ``select(K)`` returns a tuple whose first two entries are the block's
     kernel distances, ascending along each row, and their candidate
     columns, both ``(qb, K')``; further entries ride along (the device
-    select's arena positions).  ``nvalid[i]`` counts row ``i``'s real
+    select's arena positions and payload rows).  ``nvalid[i]`` counts row ``i``'s real
     candidates (columns at or past it are padding) and ``qn[i]`` is its
     query norm (0 for PQ).  While a row with more than ``K'`` candidates
     still has its ``K'``-th value inside its band, ``K`` doubles, up to
@@ -411,19 +420,36 @@ def _l2_xla(q, a):
     return qn - 2.0 * dots + an[None]
 
 
+def _gather_rows(payload, rows):
+    """``payload[rows]`` on the device: a block's arena, or the rows its
+    exact re-score reads.  Every row number is in range by construction
+    (padding slots hold row 0, which no candidate column maps to)."""
+    return payload.at[rows].get(mode="promise_in_bounds")
+
+
+@functools.lru_cache(maxsize=None)
+def _row_puller():
+    """Jitted :func:`_gather_rows`: the rows of a block's cut in one pull
+    (after a host select, or a band wider than ``SHORTLIST_ROWS``)."""
+    return _jax().jit(_gather_rows)
+
+
+# The IVF scorers take the device-resident payload and the block's
+# padded arena rows, gather the arena and score it in one program.
+
 @functools.lru_cache(maxsize=None)
 def _flat_scorers():
     jax, jnp = _jax(), _jax().numpy
 
     @functools.partial(jax.jit, static_argnames=("interpret",))
-    def pallas(q, a, interpret):
+    def pallas(q, payload, rows, interpret):
         from ..kernels.l2_topk import l2_dist
 
-        return l2_dist(q, a, interpret=interpret)
+        return l2_dist(q, _gather_rows(payload, rows), interpret=interpret)
 
     @jax.jit
-    def xla(q, a):
-        return _l2_xla(q, a)
+    def xla(q, payload, rows):
+        return _l2_xla(q, _gather_rows(payload, rows))
 
     return {"pallas": pallas, "xla": xla}
 
@@ -433,16 +459,18 @@ def _adc_scorers():
     jax, jnp = _jax(), _jax().numpy
 
     @functools.partial(jax.jit, static_argnames=("interpret",))
-    def pallas(luts, codes, interpret):
+    def pallas(luts, payload, rows, interpret):
         from ..kernels.pq_adc import pq_adc
 
         # vmap over per-query LUTs; codes (the arena) are shared.
+        codes = _gather_rows(payload, rows)
         return jax.vmap(
             lambda lut: pq_adc(codes, lut, interpret=interpret)
         )(luts)
 
     @jax.jit
-    def xla(luts, codes):
+    def xla(luts, payload, rows):
+        codes = _gather_rows(payload, rows)
         m = codes.shape[1]
         sub = jnp.arange(m)[None, :]
 
@@ -466,7 +494,9 @@ def _device_selector():
     segmented top-k (``repro.kernels.seg_topk``) cuts each row to its
     ``k`` smallest ``(value, column)`` pairs — so the ``(qb, C_pad)``
     distance block never crosses the device boundary; only ``(qb, k)``
-    values, candidate columns and arena positions return to the host.
+    values, candidate columns and arena positions return to the host,
+    with the payload rows (``payload[arena_rows[pos]]``) of each row's
+    first ``min(k, SHORTLIST_ROWS)`` columns for the exact re-score.
 
     The map is a dense compare over the P probes, with no search loop and
     no per-column gather: probe ``j`` owns columns ``[lo_j, cum_j)`` of
@@ -481,7 +511,8 @@ def _device_selector():
 
     @functools.partial(jax.jit,
                        static_argnames=("c_pad", "k", "engine", "interpret"))
-    def run(dmat, probes, start_of, size_of, c_pad, k, engine, interpret):
+    def run(dmat, probes, start_of, size_of, payload, arena_rows, c_pad, k,
+            engine, interpret):
         from ..kernels.seg_topk import seg_topk, seg_topk_xla
 
         pp = size_of[probes]                       # (qb_pad, P)
@@ -502,7 +533,9 @@ def _device_selector():
         else:
             vals, cols = seg_topk_xla(d, lens, k)
         pos_sel = jnp.take_along_axis(pos, cols, axis=1)
-        return vals, cols, pos_sel
+        rows_sel = _gather_rows(payload, _gather_rows(
+            arena_rows, pos_sel[:, :min(k, SHORTLIST_ROWS)]))
+        return vals, cols, pos_sel, rows_sel
 
     return run
 
@@ -584,8 +617,7 @@ class _Stages:
     dispatched before.
     """
 
-    def __init__(self, jnp):
-        self.jnp = jnp
+    def __init__(self):
         self.seconds = {"arena": 0.0, "upload": 0.0, "select": 0.0,
                         "rescore": 0.0}
         self.upload_bytes = 0
@@ -598,9 +630,10 @@ class _Stages:
             yield
         self.seconds[name] += time.perf_counter() - t0
 
-    def put(self, a: np.ndarray):
-        self.upload_bytes += a.nbytes
-        return self.jnp.asarray(a)
+    def put(self, *arrays: np.ndarray):
+        """Hand ``arrays`` to the device in one batched copy."""
+        self.upload_bytes += sum(a.nbytes for a in arrays)
+        return _jax().device_put(arrays)
 
     def dispatch(self, signature: tuple, fn, *args, **kwargs):
         if signature in _DISPATCHED:
@@ -613,9 +646,12 @@ class _Stages:
 
 
 # static signatures ``(kind, qb_pad, u_pad, P, c_pad_b, K, engine)`` of the
-# scorer and device-select programs dispatched so far (``kind`` names the
-# program and its fixed widths: ``flat:<d>``, ``pq:<m>:<dtype>``,
-# ``select:<nlist>``; a scorer has no P, c_pad_b or K and gives 0).  jit
+# scorer, device-select and re-score pull programs dispatched so far
+# (``kind`` names the program and its fixed shapes: ``flat:<n>x<d>:<dtype>``
+# and ``pq:<n>x<m>:<dtype>`` for the payload a scorer gathers from,
+# ``select:<nlist>:<n>x<w>:<dtype>``, and ``rows:<n>x<w>:<dtype>`` for the
+# host cut's row pull, whose padded row count stands in ``u_pad``; a
+# field a program lacks gives 0).  jit
 # caches are process-wide, so the set is too: a signature missing here
 # traces and compiles (or loads from the persistent cache) inside its
 # dispatch.
@@ -654,6 +690,12 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
     reproduces the unsharded output bit-for-bit even under duplicate
     vectors (repro.shard.service).
 
+    The index's payload is placed on the device by the first call (span
+    ``scan.payload_put``, ``stats.payload_put_bytes``); each block
+    uploads only its arena's int32 payload rows, gathers the arena on
+    the device inside the scoring program, and pulls its short-lists'
+    rows back in one transfer for the exact re-score.
+
     Each stage runs under a profiler span (``scan.search`` around the
     call; ``scan.coarse_probes``, ``scan.arena``, ``scan.upload``,
     ``scan.score``, ``scan.select``, ``scan.rescore`` and ``ids.resolve``
@@ -662,7 +704,6 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
     """
     from .pq import ProductQuantizer
 
-    jnp = _jax().numpy
     engine = _resolve_engine(engine)
     if select not in ("auto", "host", "device"):
         raise ValueError(f"unknown select mode {select!r} "
@@ -682,11 +723,17 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
             select_min = SELECT_MIN_CPU if interpret else 1
         if use_pq:
             scorer = _adc_scorers()[engine]
-            score_kind = f"pq:{index.codes.shape[1]}:{index.codes.dtype}"
         else:
             scorer = _flat_scorers()[engine]
-            score_kind = f"flat:{index.d}"
         score_kw = {"interpret": interpret} if engine == "pallas" else {}
+        # the payload goes to the device once (the first search, or the
+        # first after an add or a load); every block gathers from it there
+        payload = index.payload
+        payload_put_bytes = payload.place()
+        payload_d = payload.device
+        puller = _row_puller()
+        rows_kind = "{}x{}:{}".format(*payload.shape, payload.dtype)
+        score_kind = f"{'pq' if use_pq else 'flat'}:{rows_kind}"
 
         offsets, sizes = index.offsets, index.sizes
         ndis = 0
@@ -694,7 +741,8 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
         host_block_bytes = 0
         n_dev_select = 0
         select_calls = 0
-        stages = _Stages(jnp)
+        rescore_rows = 0
+        stages = _Stages()
         distinct: set = set()
         cache = index.decoded_cache
         decodes_before, decode_s_before = cache.decodes, cache.decode_s
@@ -717,7 +765,7 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
             nbatches += 1
             blk_probes = probes[q0:q1]
             with stages.stage("arena"):
-                # --- dedup probed clusters; build the arena ----------------
+                # --- dedup probed clusters; the arena's payload rows -------
                 uniq = np.unique(blk_probes)
                 uniq_sizes = sizes[uniq].astype(np.int64)
                 keep = uniq_sizes > 0
@@ -739,61 +787,69 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
                     rank_of[np.arange(qb)[:, None], blk_probes] = np.arange(
                         blk_probes.shape[1], dtype=np.uint64)[None]
 
-                # --- per-query padded candidate rows (probe order == oracle)
+                # --- per-query candidate rows (probe order == oracle) ------
                 pp_sizes = size_of[blk_probes]              # (qb, P)
                 cand_lens = pp_sizes.sum(axis=1)
+                live = np.flatnonzero(cand_lens)
                 ndis += int(cand_lens.sum())
                 c_pad = int(cand_lens.max()) if qb else 0
                 if c_pad == 0:
                     continue
-                flat_pos = _spans_concat(start_of[blk_probes].ravel(),
-                                         pp_sizes.ravel())
-                cand_pos = np.full((qb, c_pad), -1, dtype=np.int64)
-                row_ids = np.repeat(np.arange(qb), cand_lens)
-                col_ids = np.concatenate(
-                    [np.arange(c) for c in cand_lens]
-                ) if qb else np.zeros(0, np.int64)
-                cand_pos[row_ids, col_ids] = flat_pos
+                on_device = _resolve_select(select, c_pad, select_min)
+                if not on_device:
+                    # the host cut reads each candidate's arena position
+                    flat_pos = _spans_concat(start_of[blk_probes].ravel(),
+                                             pp_sizes.ravel())
+                    cand_pos = np.full((qb, c_pad), -1, dtype=np.int64)
+                    row_ids = np.repeat(np.arange(qb), cand_lens)
+                    col_ids = np.concatenate(
+                        [np.arange(c) for c in cand_lens])
+                    cand_pos[row_ids, col_ids] = flat_pos
 
                 # bucketed padding (not fixed query_block): a max-wait flush
                 # of a few queries must not score query_block-worth of
-                # phantom LUTs/rows
+                # phantom LUTs/rows.  Padding arena slots hold payload row
+                # 0; no candidate column maps to them.
                 u_pad = _bucket(u_rows)
                 qb_pad = _bucket(qb, floor=8)
+                rows_pad = np.zeros(u_pad, np.int32)
+                rows_pad[:u_rows] = arena_rows
+                # the device select's per-block metadata: padded probes and
+                # the dense cluster -> arena span maps
+                meta = ()
+                if on_device:
+                    probes_pad = np.zeros((qb_pad, blk_probes.shape[1]),
+                                          np.int32)
+                    probes_pad[:qb] = blk_probes
+                    meta = (probes_pad, np.maximum(start_of, 0).astype(
+                        np.int32), size_of.astype(np.int32))
                 if use_pq:
-                    arena = np.zeros((u_pad, index.codes.shape[1]),
-                                     index.codes.dtype)
-                    arena[:u_rows] = index.codes[arena_rows]
                     lhs = np.zeros((qb_pad,) + tables.shape[1:], np.float32)
                     lhs[:qb] = tables[q0:q1]
                 else:
-                    arena = np.zeros((u_pad, index.d), np.float32)
-                    arena[:u_rows] = index.vecs[arena_rows]
                     lhs = np.zeros((qb_pad, index.d), np.float32)
                     lhs[:qb] = queries[q0:q1]
                     qn_host = np.einsum("qd,qd->q",
                                         queries[q0:q1].astype(np.float32),
                                         queries[q0:q1].astype(np.float32))
 
-            # --- blocked scoring (lhs: the query block or its LUTs) --------
+            # --- gather the arena and score it (lhs: queries or LUTs) ------
             with stages.stage("upload"):
-                lhs_d, arena_d = stages.put(lhs), stages.put(arena)
+                lhs_d, rows_d, *meta_d = stages.put(lhs, rows_pad, *meta)
             with span("scan.score"):
                 dmat = stages.dispatch(
                     (score_kind, qb_pad, u_pad, 0, 0, 0, engine), scorer,
-                    lhs_d, arena_d, **score_kw)
+                    lhs_d, payload_d, rows_d, **score_kw)
 
-            def finish(i, qi, pos):
-                # exact re-score of one query's short-list; ``pos`` holds the
-                # selected arena positions in candidate (oracle concat)
+            def finish(i, qi, pos, rows):
+                # exact re-score of one query's short-list: ``pos`` holds
+                # the selected arena positions in candidate (oracle concat)
                 # order, so select_topk's stable tie-break reproduces the
-                # oracle's.
-                rows = arena_rows[pos]
+                # oracle's; ``rows`` are their payload rows.
                 if use_pq:
-                    d_exact = ProductQuantizer.adc_score(
-                        index.codes[rows], tables[qi])
+                    d_exact = ProductQuantizer.adc_score(rows, tables[qi])
                 else:
-                    d_exact = score_rows_flat(index.vecs[rows], queries[qi])
+                    d_exact = score_rows_flat(rows, queries[qi])
                 best = select_topk(d_exact, topk)
                 n_found = best.shape[0]
                 all_d[qi, :n_found] = d_exact[best]
@@ -808,8 +864,29 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
                     res_key.append(pack_merge_keys(
                         rank_of[i, uniq[span_of]], p - arena_start[span_of]))
 
+            def rescore(pos, rows=None):
+                # re-score each live query's short-list: ``pos[j]`` are
+                # query ``live[j]``'s selected arena positions, ``rows[j]``
+                # their payload rows; without ``rows`` every row of the
+                # block comes back from the device in one pull
+                nonlocal rescore_rows
+                lens = [len(p) for p in pos]
+                rescore_rows += sum(lens)
+                if rows is None:
+                    want = arena_rows[np.concatenate(pos)]
+                    r_pad = _bucket(len(want), floor=256)
+                    want_pad = np.zeros(r_pad, np.int32)
+                    want_pad[:len(want)] = want
+                    got = stages.dispatch(
+                        (f"rows:{rows_kind}", 0, r_pad, 0, 0, 0, "xla"),
+                        puller, payload_d, *stages.put(want_pad))
+                    rows = np.split(np.asarray(got)[:len(want)],
+                                    np.cumsum(lens)[:-1])
+                for i, p, r in zip(live, pos, rows):
+                    finish(i, q0 + i, p, r)
+
             qn = np.zeros(qb) if use_pq else qn_host
-            if _resolve_select(select, c_pad, select_min):
+            if on_device:
                 # --- device-side segmented top-k ---------------------------
                 # the (qb, C_pad) block stays on device: a jitted gather +
                 # seg_topk returns (qb, K) shortlist values / candidate
@@ -819,33 +896,33 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
                 with stages.stage("select"):
                     runner = _device_selector()
                     c_pad_b = _bucket(c_pad, floor=128)
-                    probes_pad = np.zeros((qb_pad, blk_probes.shape[1]),
-                                          np.int32)
-                    probes_pad[:qb] = blk_probes
-                    start32 = np.maximum(start_of, 0).astype(np.int32)
-                    size32 = size_of.astype(np.int32)
 
                     def select_k(K):
                         nonlocal select_calls, host_block_bytes
                         select_calls += 1
                         out = stages.dispatch(
-                            (f"select:{index.nlist}", qb_pad, u_pad,
-                             blk_probes.shape[1], c_pad_b, K, engine),
-                            runner, dmat, stages.put(probes_pad),
-                            stages.put(start32), stages.put(size32),
-                            c_pad=c_pad_b, k=K, engine=engine,
-                            interpret=interpret)
-                        out = [np.asarray(a) for a in out]
-                        host_block_bytes += sum(a.nbytes for a in out)
+                            (f"select:{index.nlist}:{rows_kind}", qb_pad,
+                             u_pad, blk_probes.shape[1], c_pad_b, K, engine),
+                            runner, dmat, *meta_d, payload_d, rows_d,
+                            c_pad=c_pad_b, k=K,
+                            engine=engine, interpret=interpret)
+                        # one round of copies: the shortlist and its rows
+                        out = _jax().device_get(list(out))
+                        host_block_bytes += sum(a.nbytes for a in out[:3])
                         return [a[:qb] for a in out]
 
-                    (_, _, sel_pos), _, cut = _shortlist(
+                    (_, _, sel_pos, sel_rows), _, cut = _shortlist(
                         select_k, cand_lens, topk, index.d, qn, c_pad_b)
                 with stages.stage("rescore"):
-                    for i in range(qb):
-                        if cand_lens[i]:
-                            finish(i, q0 + i,
-                                   sel_pos[i, cut(i)].astype(np.int64))
+                    picks = [cut(i) for i in live]
+                    pos = [sel_pos[i, c].astype(np.int64)
+                           for i, c in zip(live, picks)]
+                    # a band past the rows that came with the shortlist
+                    # sends the block to one pull after the cut
+                    wide = any(c.size and c.max() >= sel_rows.shape[1]
+                               for c in picks)
+                    rescore(pos, None if wide else
+                            [sel_rows[i, c] for i, c in zip(live, picks)])
             else:
                 # --- host-side stable top-k over the pulled padded block ---
                 with stages.stage("select"):
@@ -867,13 +944,11 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
                     # cut's candidate order is the oracle's tie order.
                     _, _, cut = _shortlist(lambda K: sorted_blk, cand_lens,
                                            topk, index.d, qn, c_pad)
-                    for i in range(qb):
-                        if cand_lens[i]:
-                            finish(i, q0 + i, cand_pos[i, order[i, cut(i)]])
+                    rescore([cand_pos[i, order[i, cut(i)]] for i in live])
             with stages.stage("arena"):
-                # release the block's arena on the host and the device
+                # release the block's arrays on the host and the device
                 # inside the stage, not at the next fill or the return
-                del arena, lhs, arena_d, lhs_d, dmat
+                del lhs, rows_pad, meta, lhs_d, rows_d, meta_d, dmat
 
         # --- late id resolution: one pass over every winning pair ----------
         t_res = time.perf_counter()
@@ -909,6 +984,8 @@ def batched_search(index, queries: np.ndarray, nprobe: int = 16,
             upload_bytes=stages.upload_bytes,
             select_calls=select_calls,
             new_shapes=stages.new_shapes,
+            payload_put_bytes=payload_put_bytes,
+            rescore_rows=rescore_rows,
             merge_keys=all_keys,
         )
     return all_ids, all_d, stats

@@ -75,8 +75,8 @@ class SearchStats:
     # -- stages of the batched IVF scan (0 elsewhere); each ``*_s`` is the
     # perf_counter time of the profiler span of the same stage, summed over
     # the call's query blocks -------------------------------------------------
-    arena_s: float = 0.0       # scan.arena: dedup, arena fill and release
-    upload_s: float = 0.0      # scan.upload: host->device copy of the arena
+    arena_s: float = 0.0       # scan.arena: dedup, arena rows, their release
+    upload_s: float = 0.0      # scan.upload: host->device copy of the rows
     select_s: float = 0.0      # scan.select: top-k cut incl. device wait
     rescore_s: float = 0.0     # scan.rescore: exact host re-score
     decode_s: float = 0.0      # ids.decode: id-list decodes (in id_resolve_s)
@@ -86,6 +86,9 @@ class SearchStats:
     upload_bytes: int = 0      # bytes handed to the device (retries included)
     select_calls: int = 0      # device-select runs incl. K-doubling retries
     new_shapes: int = 0        # scorer/select signatures first seen this call
+    payload_put_bytes: int = 0  # scan.payload_put: payload bytes placed on
+                               # the device (0 once it is there)
+    rescore_rows: int = 0      # payload rows the exact re-score read back
     # -- sharded-serving aggregation (repro.shard) ---------------------------
     shards: int = 0            # shards scattered to (0 = unsharded call)
     shards_failed: int = 0     # shards that missed the deadline / died
@@ -133,5 +136,7 @@ def combine_stats(parts: Sequence[SearchStats], *, wall_s: float,
         out.upload_bytes += s.upload_bytes
         out.select_calls += s.select_calls
         out.new_shapes += s.new_shapes
+        out.payload_put_bytes += s.payload_put_bytes
+        out.rescore_rows += s.rescore_rows
         out.retries += s.retries
     return out

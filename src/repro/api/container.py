@@ -36,6 +36,7 @@ import numpy as np
 
 from ..ann.graph import GraphIndex
 from ..ann.ivf import IVFIndex
+from ..ann.payload import PayloadStore
 from ..ann.pq import ProductQuantizer
 from ..core.ans import StreamANS
 from ..core.container import (SectionReader, SectionWriter, pack_joint_ids,
@@ -104,12 +105,12 @@ def _pack_ivf_sections(w: SectionWriter, meta: dict, ivf: IVFIndex) -> None:
             "epochs": [pack_polya_sections(w, blob, prefix=f"code{e}")
                        for e, blob in enumerate(ivf._code_blobs)],
         }
-    elif ivf.codes is not None:
-        w.add("codes_raw", ivf.codes.tobytes())
-        meta["code"] = {"m": int(ivf.codes.shape[1]), "raw": True}
+    elif ivf.pq is not None:
+        w.add("codes_raw", ivf.payload.host().tobytes())
+        meta["code"] = {"m": int(ivf.payload.shape[1]), "raw": True}
     else:
         meta["code"] = None
-        w.add("vecs", ivf.vecs.astype(np.float32).tobytes())
+        w.add("vecs", ivf.payload.host().astype(np.float32).tobytes())
 
 
 def _rle(a: np.ndarray):
@@ -251,17 +252,15 @@ def _unpack_ivf(r: SectionReader, spec: IndexSpec) -> IVFIndex:
     # payload (cluster-grouped storage order)
     cm = m["code"]
     if cm is None:
-        ivf.codes = None
         # shards store fewer rows than the global universe n
-        ivf.vecs = _f32(r.section("vecs"), (int(ivf.sizes.sum()), d))
+        ivf.payload = PayloadStore(
+            _f32(r.section("vecs"), (int(ivf.sizes.sum()), d)))
         ivf._code_blobs = None
     elif cm.get("raw"):
-        ivf.vecs = None
-        ivf.codes = np.frombuffer(r.section("codes_raw"), np.uint8).reshape(
-            -1, cm["m"]).copy()
+        ivf.payload = PayloadStore(np.frombuffer(
+            r.section("codes_raw"), np.uint8).reshape(-1, cm["m"]).copy())
         ivf._code_blobs = None
     else:
-        ivf.vecs = None
         ivf._polya = PolyaCodec()
         if r.version == 2:
             blob = unpack_polya_sections(r, [int(s) for s in ivf.sizes], cm)
@@ -277,11 +276,11 @@ def _unpack_ivf(r: SectionReader, spec: IndexSpec) -> IVFIndex:
                 ivf._code_blobs.append(blob)
                 per_epoch_codes.append(PolyaCodec().decode(blob))
         # epoch-major per-cluster chunks -> global cluster-grouped rows
-        ivf.codes = np.concatenate(
+        ivf.payload = PayloadStore(np.concatenate(
             [chunk
              for k in range(nlist)
              for per in per_epoch_codes
-             for chunk in [per[k]]], axis=0)
+             for chunk in [per[k]]], axis=0))
     ivf._decoded_cache = ivf._new_cache()
     return ivf
 

@@ -275,11 +275,12 @@ class IVFApiIndex(_SpecMixin):
         # cluster shard (whose id universe stays the global n)
         n = int(idx.sizes.sum())
         id_bytes = idx.id_bits() / 8.0
-        if idx.codes is not None:
-            payload = idx.codes.shape[1] * n * idx.code_bits_per_element() / 8.0
-            payload_unc = idx.codes.nbytes
+        payload_unc = idx.payload.nbytes
+        if idx.pq is not None:
+            payload = (idx.payload.shape[1] * n
+                       * idx.code_bits_per_element() / 8.0)
         else:
-            payload = payload_unc = idx.vecs.nbytes
+            payload = payload_unc
         cache = idx.decoded_cache.stats()
         return {
             "n": n,

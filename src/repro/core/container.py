@@ -145,9 +145,9 @@ def pack_ivf(index) -> bytes:
     if getattr(index, "_code_blob", None) is not None:
         # v1 manifests carry only {"m"} for the polya payload
         code_meta = {"m": pack_polya_sections(w, index._code_blob)["m"]}
-    elif index.codes is not None:
-        w.add("codes_raw", index.codes.tobytes())
-        code_meta = {"m": int(index.codes.shape[1]), "raw": True}
+    elif index.pq is not None:
+        w.add("codes_raw", index.payload.host().tobytes())
+        code_meta = {"m": int(index.payload.shape[1]), "raw": True}
     return w.finish(_MAGIC, _VERSION, {
         "n": int(index.n), "d": int(index.d), "nlist": int(index.nlist),
         "sizes": sizes, "code": code_meta,

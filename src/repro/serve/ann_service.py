@@ -50,7 +50,8 @@ __all__ = ["AnnService", "AddTicket", "BatchPolicy", "Ticket"]
 # SearchStats fields of the batched IVF scan's stages, summed into stats()
 SCAN_STAGE_KEYS = ("arena_s", "upload_s", "select_s", "rescore_s",
                    "decode_s", "decode_ids", "wt_select_s", "wt_select_ids",
-                   "upload_bytes", "select_calls", "new_shapes")
+                   "upload_bytes", "select_calls", "new_shapes",
+                   "payload_put_bytes", "rescore_rows")
 
 
 @dataclasses.dataclass
@@ -342,6 +343,10 @@ class AnnService:
         * ``upload_bytes`` / ``select_calls`` / ``new_shapes`` — bytes
           copied to the device, device-select runs (K-doubling retries
           included) and first-seen scorer/select signatures (compiles).
+        * ``payload_put_bytes`` / ``rescore_rows`` — bytes of the IVF
+          payload placed on the device (the first search after a build,
+          an add or a load; 0 while serving) and payload rows pulled back
+          for the exact re-score.
         """
         bs = np.asarray(self._batch_sizes, np.float64)
         ws = np.asarray(self._waits, np.float64)

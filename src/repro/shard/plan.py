@@ -42,6 +42,7 @@ import numpy as np
 
 from ..ann.graph import GraphIndex, build_hnsw, build_nsg
 from ..ann.ivf import IVFIndex
+from ..ann.payload import PayloadStore
 from ..ann.scan import _spans_concat
 from ..core.polya import PolyaCodec
 from ..api.container import load_index, save_index
@@ -184,6 +185,7 @@ def _split_ivf(src: IVFIndex, owner: np.ndarray,
     and repro.core.epoch for why that buys bit-parity)."""
     out = []
     starts = src.offsets[:-1]
+    held = src.payload.host()
     for s in range(nshards):
         mask = owner == s
         sh = IVFIndex(nlist=src.nlist, id_codec=src.id_codec, pq=src.pq,
@@ -199,10 +201,8 @@ def _split_ivf(src: IVFIndex, owner: np.ndarray,
                      for k in range(src.nlist)]
         rows = _spans_concat(starts[mask].astype(np.int64),
                              src.sizes[mask].astype(np.int64))
-        if src.codes is not None:
-            sh.codes, sh.vecs = src.codes[rows], None
-        else:
-            sh.codes, sh.vecs = None, src.vecs[rows]
+        sh_rows = held[rows]
+        sh.payload = PayloadStore(sh_rows)
         # owned epoch blobs are the monolithic ones verbatim (same relative
         # list, same universe -> same bytes); unowned clusters empty
         sh._ids = src._ids.split(mask, src._lists)
@@ -213,8 +213,8 @@ def _split_ivf(src: IVFIndex, owner: np.ndarray,
             sh._polya = PolyaCodec()
             sh._code_blobs = [
                 sh._polya.encode(
-                    [sh.codes[sh.offsets[k] + cum[e, k]:
-                              sh.offsets[k] + cum[e + 1, k]]
+                    [sh_rows[sh.offsets[k] + cum[e, k]:
+                             sh.offsets[k] + cum[e + 1, k]]
                      for k in range(sh.nlist)])
                 for e in range(sh._ids.n_epochs)]
         else:

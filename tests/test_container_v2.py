@@ -178,4 +178,4 @@ def test_v1_container_still_unpacks(data, centroids, pq):
     assert manifest["n"] == len(data[0])
     for k in range(NLIST):
         np.testing.assert_array_equal(lists[k], idx.ivf._lists[k])
-    np.testing.assert_array_equal(codes, idx.ivf.codes)
+    np.testing.assert_array_equal(codes, idx.ivf.payload.host())

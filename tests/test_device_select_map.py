@@ -14,7 +14,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.ann.scan import _bucket, _device_selector, _spans_concat
+from repro.ann.scan import (SHORTLIST_ROWS, _bucket, _device_selector,
+                            _spans_concat)
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -104,9 +105,14 @@ def test_device_map_matches_host_spans(P, case):
     dmat, probes, start_of, size_of, c_pad, lens, cand_pos = _block(
         rng, P, qb=6, pad_rows=pad_rows, zero_mode=zero_mode, wide=wide)
     k = c_pad if k_all else 32            # k = c_pad: every padding slot
-    vals, cols, pos_sel = (np.asarray(a) for a in _device_selector()(
-        dmat, probes, start_of, size_of, c_pad=c_pad, k=k, engine="xla",
-        interpret=None))
+    payload = rng.standard_normal((500, 4)).astype(np.float32)
+    arena_rows = rng.integers(0, 500, dmat.shape[1]).astype(np.int32)
+    vals, cols, pos_sel, rows_sel = (np.asarray(a) for a in _device_selector()(
+        dmat, probes, start_of, size_of, payload, arena_rows, c_pad=c_pad,
+        k=k, engine="xla", interpret=None))
+    # the payload rows of each row's first columns ride along
+    np.testing.assert_array_equal(
+        rows_sel, payload[arena_rows[pos_sel[:, :min(k, SHORTLIST_ROWS)]]])
 
     # reference: gather through the host's map, then a stable ascending
     # (value, column) cut of each row
@@ -136,7 +142,9 @@ def test_device_map_traces_no_loop(engine):
     jaxpr = jax.make_jaxpr(run)(sds((qb, 1 << 19), np.float32),
                                 sds((qb, 16), np.int32),
                                 sds((1024,), np.int32),
-                                sds((1024,), np.int32))
+                                sds((1024,), np.int32),
+                                sds((1 << 20, 128), np.float32),
+                                sds((1 << 19,), np.int32))
 
     def eqns(jx):
         for eqn in jx.eqns:

@@ -57,7 +57,7 @@ def test_ivf_container_roundtrip():
     assert manifest["n"] == 30_000
     for k in range(64):
         np.testing.assert_array_equal(lists[k], np.sort(idx._lists[k]))
-    np.testing.assert_array_equal(codes, idx.codes)
+    np.testing.assert_array_equal(codes, idx.payload.host())
     np.testing.assert_allclose(cents, idx.centroids, atol=0.5)
     # blob must beat the compact layout (ids at ceil(log2 n) + raw codes)
     compact_bytes = (np.ceil(np.log2(30_000)) / 8) * 30_000 + 30_000 * 8

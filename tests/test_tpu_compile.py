@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 D = 128          # SIFT1M width
 QB = 64          # scan engines' default query block
 ARENA = 16384    # IVF arena rows (power-of-two bucket)
+N = 1_000_000    # SIFT1M payload rows, device-resident
 
 
 @pytest.fixture(scope="module")
@@ -62,7 +63,8 @@ def test_l2_dist_ivf_blocks_compile(one_chip):
 
     scorer = _flat_scorers()["pallas"]
     _assert_kernel(scorer.lower(_spec(one_chip, (QB, D)),
-                                _spec(one_chip, (ARENA, D)),
+                                _spec(one_chip, (N, D)),
+                                _spec(one_chip, (ARENA,), jnp.int32),
                                 interpret=False))
 
 
@@ -90,8 +92,50 @@ def test_pq_adc_vmapped_compiles(one_chip):
 
     scorer = _adc_scorers()["pallas"]
     _assert_kernel(scorer.lower(_spec(one_chip, (QB, 8, 256)),
-                                _spec(one_chip, (ARENA, 8), jnp.uint8),
+                                _spec(one_chip, (N, 8), jnp.uint8),
+                                _spec(one_chip, (ARENA,), jnp.int32),
                                 interpret=False))
+
+
+@pytest.mark.parametrize("kind,width,dtype,hlo", [
+    ("flat", D, jnp.float32, "f32"), ("pq", 8, jnp.uint8, "u8")])
+def test_arena_gather_compiles(one_chip, kind, width, dtype, hlo):
+    """The scorer gathers a 2^19-row arena from the device-resident
+    payload inside its own program: exactly one gather fusion makes the
+    ``(2^19, width)`` arena, which the kernel then reads.  The re-score's
+    rows come with the device select, one gather of ``QB *
+    SHORTLIST_ROWS`` rows, or, after a host cut, in one pull."""
+    from repro.ann.scan import (SHORTLIST_ROWS, _adc_scorers,
+                                _device_selector, _flat_scorers, _row_puller)
+
+    u_pad = 1 << 19
+    lhs = (QB, D) if kind == "flat" else (QB, width, 256)
+    scorer = (_flat_scorers if kind == "flat" else _adc_scorers)()["pallas"]
+    text = scorer.lower(_spec(one_chip, lhs),
+                        _spec(one_chip, (N, width), dtype),
+                        _spec(one_chip, (u_pad,), jnp.int32),
+                        interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text and "while(" not in text
+
+    def gathers(text, shape):
+        pat = re.compile(rf"^\s*(ROOT )?%\S+ = {hlo}\[{shape}\]\S* fusion\("
+                         r'.*kind=kCustom.*op_name="[^"]*gather"')
+        return [ln for ln in text.splitlines() if pat.match(ln)]
+
+    assert len(gathers(text, f"{u_pad},{width}")) == 1
+    pull = _row_puller().lower(_spec(one_chip, (N, width), dtype),
+                               _spec(one_chip, (2048,), jnp.int32))
+    assert len(gathers(pull.compile().as_text(), f"2048,{width}")) == 1
+    sel = _device_selector().lower(
+        _spec(one_chip, (QB, u_pad)), _spec(one_chip, (QB, 16), jnp.int32),
+        _spec(one_chip, (1024,), jnp.int32),
+        _spec(one_chip, (1024,), jnp.int32),
+        _spec(one_chip, (N, width), dtype),
+        _spec(one_chip, (u_pad,), jnp.int32),
+        c_pad=32768, k=64, engine="pallas", interpret=False)
+    text = sel.compile().as_text()
+    assert "while(" not in text
+    assert len(gathers(text, f"{QB * SHORTLIST_ROWS},{width}")) == 1
 
 
 @pytest.mark.parametrize("n,k", [(ARENA, 32), (1000, 10)])
@@ -111,6 +155,8 @@ def test_device_selector_compiles(one_chip):
                              _spec(one_chip, (QB, 16), jnp.int32),
                              _spec(one_chip, (1024,), jnp.int32),
                              _spec(one_chip, (1024,), jnp.int32),
+                             _spec(one_chip, (N, D)),
+                             _spec(one_chip, (ARENA,), jnp.int32),
                              c_pad=ARENA, k=32, engine="pallas",
                              interpret=False))
 
@@ -127,6 +173,8 @@ def test_device_selector_map_has_no_loop(one_chip):
         _spec(one_chip, (QB, 16), jnp.int32),
         _spec(one_chip, (1024,), jnp.int32),
         _spec(one_chip, (1024,), jnp.int32),
+        _spec(one_chip, (N, D)),
+        _spec(one_chip, (1 << 19,), jnp.int32),
         c_pad=c_pad, k=32, engine="pallas", interpret=False,
     ).compile().as_text()
     assert "while(" not in text

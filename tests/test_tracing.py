@@ -67,9 +67,11 @@ def _host_spans(log_dir):
 
 
 def _nested(spans, child, parent):
-    """Every ``child`` span lies inside a ``parent`` span on its thread."""
+    """Every ``child`` span lies inside a ``parent`` span on its thread
+    (``parent``: one name, or a tuple of names any of which may hold it)."""
+    parents = (parent,) if isinstance(parent, str) else parent
     kids = [s for s in spans if s[0] == child]
-    outer = [s for s in spans if s[0] == parent]
+    outer = [s for s in spans if s[0] in parents]
     return bool(kids) and all(
         any(p[1] == c[1] and p[2] <= c[2] and c[3] <= p[3] for p in outer)
         for c in kids)
@@ -96,7 +98,9 @@ def test_profile_holds_the_span_tree(tmp_path, monkeypatch, data, kind):
             ("scan.search", "scan.rescore"),
             ("scan.search", "ids.resolve"),
             ("ids.resolve", "ids.decode"),
-            ("scan.score", "scan.compile")]
+            ("scan.search", "scan.payload_put"),
+            # the scorer's first dispatch, and the re-score's row pull
+            (("scan.score", "scan.rescore"), "scan.compile")]
     if kind == "pq":
         tree.append(("scan.search", "pq.adc_tables"))
     for parent, child in tree:
@@ -124,8 +128,14 @@ def test_wt_select_span_only_where_a_tree_resolves(tmp_path, data, codec):
         assert _nested(spans, "ids.decode", "ids.resolve")
 
 
-def _expected_upload(idx, queries, nprobe, query_block, select_calls):
-    """Bytes ``batched_search`` hands to the device, from padded shapes."""
+def _expected_upload(idx, queries, nprobe, query_block, select_calls,
+                     rescore_rows):
+    """Bytes ``batched_search`` hands to the device, from padded shapes.
+
+    The payload itself is on the device already: per block only the
+    arena's int32 payload rows cross, and, where the host cuts, the
+    int32 row numbers of the one re-score pull (``rescore_rows`` of the
+    block, padded)."""
     probes = scan.coarse_probes(queries, idx.centroids, nprobe)
     total = 0
     for q0 in range(0, len(queries), query_block):
@@ -134,13 +144,18 @@ def _expected_upload(idx, queries, nprobe, query_block, select_calls):
         qb_pad = scan._bucket(len(blk), floor=8)
         if idx.pq is not None:
             total += qb_pad * idx.pq.m * idx.pq.ksub * 4      # f32 LUTs
-            total += u_pad * idx.codes.shape[1] * idx.codes.itemsize
         else:
-            total += (qb_pad + u_pad) * idx.d * 4             # f32 rows
+            total += qb_pad * idx.d * 4                       # f32 queries
+        total += u_pad * 4                                    # arena rows
         if select_calls:
-            # per run: (qb_pad, P) int32 probes + two int32 nlist maps
-            total += select_calls[q0 // query_block] * (
-                qb_pad * blk.shape[1] * 4 + 2 * idx.nlist * 4)
+            # once a block, whatever its select runs: (qb_pad, P) int32
+            # probes + two int32 nlist maps; the shortlist's rows ride
+            # with each run (no band here is wider than SHORTLIST_ROWS,
+            # so nothing is pulled after the cut)
+            total += qb_pad * blk.shape[1] * 4 + 2 * idx.nlist * 4
+        else:
+            total += scan._bucket(rescore_rows[q0 // query_block],
+                                  floor=256) * 4              # pulled rows
     return total
 
 
@@ -149,17 +164,21 @@ def _expected_upload(idx, queries, nprobe, query_block, select_calls):
 def test_upload_bytes_follow_padded_shapes(data, kind, select):
     base, queries = data
     idx = _ivf(base, kind)
-    per_block = []
+    per_block, pulled = [], []
     for q0 in range(0, len(queries), 8):
         _, _, st = idx.search(queries[q0:q0 + 8], nprobe=4, topk=10,
                               select=select)
         per_block.append(st.select_calls)
+        pulled.append(st.rescore_rows)
     _, _, st = idx.search(queries, nprobe=4, topk=10, select=select,
                           query_block=8)
     assert st.batches == 3
     assert st.select_calls == sum(per_block)
+    assert st.rescore_rows == sum(pulled)
+    assert st.payload_put_bytes == 0          # placed by the first search
     assert st.upload_bytes == _expected_upload(
-        idx, queries, 4, 8, per_block if select == "device" else None)
+        idx, queries, 4, 8, per_block if select == "device" else None,
+        pulled)
 
 
 def test_device_select_counts_its_runs(data):
